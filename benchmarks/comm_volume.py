@@ -29,7 +29,6 @@ from .common import run_bench_subprocess
 _SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import json
     import numpy as np
     import jax
     from jax.sharding import Mesh
@@ -68,14 +67,13 @@ _SCRIPT = textwrap.dedent("""
             for reducer in ("cvc", "full"):
                 _, st, us = out[reducer]
                 name = f"comm/{scheme_name}_{reducer}_dev{d}"
-                print(f"ROW,{name},{us:.1f},"
-                      f"comm_elems={st.comm_elems};"
-                      f"comm_bytes={st.comm_bytes};"
-                      f"reduce_axis_hops={st.reduce_axis_hops};"
-                      f"full_over_cvc={ratio:.2f}")
-                print("STAT," + name + "," + json.dumps(
-                    dict(st.as_dict(), wall_us=us, scheme=scheme_name,
-                         reducer=reducer, full_over_cvc=ratio)))
+                emit(name, us,
+                     f"comm_elems={st.comm_elems};"
+                     f"comm_bytes={st.comm_bytes};"
+                     f"reduce_axis_hops={st.reduce_axis_hops};"
+                     f"full_over_cvc={ratio:.2f}",
+                     dict(st.as_dict(), wall_us=us, scheme=scheme_name,
+                          reducer=reducer, full_over_cvc=ratio))
 """)
 
 

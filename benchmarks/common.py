@@ -39,15 +39,17 @@ SUBPROC_HELPERS = textwrap.dedent("""
         return ts[len(ts) // 2]
 
     def emit(name, us, derived, stats=None):
-        import json
+        import json, jax
         print(f"ROW,{name},{us:.1f},{derived}")
-        if stats is not None:
-            samples = getattr(t, "samples", None)
-            if samples:
-                stats = dict(stats, wall_us_min=samples[0],
-                             wall_us_median=samples[len(samples) // 2],
-                             wall_us_reps=len(samples))
-            print("STAT," + name + "," + json.dumps(stats))
+        dev = jax.devices()[0]
+        stats = dict(stats or {}, platform=dev.platform,
+                     device_kind=dev.device_kind)
+        samples = getattr(t, "samples", None)
+        if samples:
+            stats = dict(stats, wall_us_min=samples[0],
+                         wall_us_median=samples[len(samples) // 2],
+                         wall_us_reps=len(samples))
+        print("STAT," + name + "," + json.dumps(stats))
 """)
 
 
@@ -65,10 +67,18 @@ def time_call(fn: Callable, *args, warmup: int = 1, iters: int = 3) -> float:
     return float(np.median(ts))
 
 
+def device_stats() -> dict:
+    """``platform`` and ``device_kind`` of this process's first JAX device,
+    so that a row measured on the CPU can never pass for a chip row."""
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind}
+
+
 def row(name: str, us: float, derived: str = "", stats: dict | None = None) -> tuple:
-    """One benchmark row.  ``stats`` (e.g. ``RunStats.as_dict()``) rides
-    along for ``run.py --emit-json``; the CSV printer ignores it."""
-    return (name, us, derived, stats)
+    """One benchmark row measured in this process.  ``stats`` (e.g.
+    ``RunStats.as_dict()``) rides along for ``run.py --emit-json``, with
+    this process's device added; the CSV printer ignores it."""
+    return (name, us, derived, dict(stats or {}, **device_stats()))
 
 
 def print_rows(rows):
@@ -83,7 +93,9 @@ def run_bench_subprocess(script: str, error_name: str, timeout: int = 900):
     ``STAT,name,<json>`` protocol into row tuples.  The ``SUBPROC_HELPERS``
     prelude (``t``/``emit``) is prepended to every script.  Emits a single
     ``<error_name>,0.0,<stderr tail>`` row when the script produced
-    nothing — ``run.py`` treats ``*/ERROR`` rows as suite failure."""
+    nothing — ``run.py`` treats ``*/ERROR`` rows as suite failure.  Each
+    row keeps the stats its child printed, which name the child's device
+    (``JAX_PLATFORMS=cpu`` pins it to the CPU even on a chip host)."""
     r = subprocess.run(
         [sys.executable, "-c", SUBPROC_HELPERS + script],
         capture_output=True, text=True,
@@ -100,7 +112,7 @@ def run_bench_subprocess(script: str, error_name: str, timeout: int = 900):
     for line in r.stdout.splitlines():
         if line.startswith("ROW,"):
             _, name, us, derived = line.split(",", 3)
-            rows.append(row(name, float(us), derived, stats.get(name)))
+            rows.append((name, float(us), derived, stats[name]))
     if not rows:
         rows.append(row(error_name, 0.0,
                         r.stderr[-200:].replace(",", ";").replace("\n", " ")))

@@ -10,13 +10,11 @@ churn-model break-even (why migration stays off).
 
 from __future__ import annotations
 
-import subprocess
-import sys
 import textwrap
 
 from repro.core.placement import ChurnModel
 
-from .common import row
+from .common import row, run_bench_subprocess
 
 _SCRIPT = textwrap.dedent("""
     import os
@@ -48,25 +46,13 @@ _SCRIPT = textwrap.dedent("""
             for sh in arr.addressable_shards:
                 shard_bytes[sh.device.id] += sh.data.size * sh.data.dtype.itemsize
         mx, mn = max(shard_bytes), max(min(shard_bytes), 1)
-        print(f"ROW,fig3/bfs_{policy},{us:.1f},"
-              f"max_dev_bytes={mx};imbalance={mx/mn:.2f}")
+        emit(f"fig3/bfs_{policy}", us,
+             f"max_dev_bytes={mx};imbalance={mx/mn:.2f}")
 """)
 
 
 def run():
-    rows = []
-    r = subprocess.run(
-        [sys.executable, "-c", _SCRIPT], capture_output=True, text=True,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
-             "JAX_PLATFORMS": "cpu"},
-        timeout=600,
-    )
-    for line in r.stdout.splitlines():
-        if line.startswith("ROW,"):
-            _, name, us, derived = line.split(",", 3)
-            rows.append(row(name, float(us), derived))
-    if not rows:
-        rows.append(row("fig3/ERROR", 0.0, r.stderr[-200:].replace(",", ";")))
+    rows = run_bench_subprocess(_SCRIPT, "fig3/ERROR", timeout=600)
     # §4.2 churn model: migrating 1 GB mid-run vs 10 µs/round locality gain
     cm = ChurnModel()
     be = cm.breakeven_rounds(1 << 30, 10e-6)

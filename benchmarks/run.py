@@ -27,6 +27,8 @@ import json
 import sys
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 from . import (algo_classes, common, comm_volume, dynamic, frameworks,
                granularity, kernels_bench, memtier, outofcore, placement,
                roofline, scaling, serving, vs_cluster)
@@ -63,6 +65,7 @@ def main() -> None:
     if args.list:
         print("\n".join(SUITES))
         return
+    enable_compile_cache()
     names = args.suite or list(SUITES)
     unknown = [n for n in names if n not in SUITES]
     if unknown:

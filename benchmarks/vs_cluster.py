@@ -36,18 +36,18 @@ _SCRIPT = textwrap.dedent("""
     # --- OB: best algorithms, single partition
     us = t(lambda: bfs.bfs_dd_sparse(g, source)[0])
     _, st = bfs.bfs_dd_sparse(g, source)
-    print(f"ROW,fig11/bfs/OB,{us:.1f},rounds={st.rounds};sync_bytes=0")
+    emit("fig11/bfs/OB", us, f"rounds={st.rounds};sync_bytes=0")
     us = t(lambda: cc.cc_pointer_jump(g)[0])
     _, st = cc.cc_pointer_jump(g)
-    print(f"ROW,fig11/cc/OB,{us:.1f},rounds={st.rounds};sync_bytes=0")
+    emit("fig11/cc/OB", us, f"rounds={st.rounds};sync_bytes=0")
 
     # --- OA: vertex programs, single partition
     us = t(lambda: bfs.bfs_dd_dense(g, source)[0])
     _, st = bfs.bfs_dd_dense(g, source)
-    print(f"ROW,fig11/bfs/OA,{us:.1f},rounds={st.rounds};sync_bytes=0")
+    emit("fig11/bfs/OA", us, f"rounds={st.rounds};sync_bytes=0")
     us = t(lambda: cc.cc_labelprop(g)[0])
     _, st = cc.cc_labelprop(g)
-    print(f"ROW,fig11/cc/OA,{us:.1f},rounds={st.rounds};sync_bytes=0")
+    emit("fig11/cc/OA", us, f"rounds={st.rounds};sync_bytes=0")
 
     # --- DM: CVC-partitioned BSP vertex programs on 8 hosts
     mesh = jax.sharding.Mesh(np.array(jax.devices()).reshape(4, 2),
@@ -56,12 +56,12 @@ _SCRIPT = textwrap.dedent("""
     label_bytes = 4 * g.n_pad  # one dense label sync per round per device
     us = t(lambda: pt.bsp_bfs(pg, mesh, ("data", "model"), source)[0])
     _, rounds = pt.bsp_bfs(pg, mesh, ("data", "model"), source)
-    print(f"ROW,fig11/bfs/DM,{us:.1f},rounds={rounds};"
-          f"sync_bytes={rounds*label_bytes*8}")
+    emit("fig11/bfs/DM", us, f"rounds={rounds};"
+         f"sync_bytes={rounds*label_bytes*8}")
     us = t(lambda: pt.bsp_cc(pg, mesh, ("data", "model"))[0])
     _, rounds = pt.bsp_cc(pg, mesh, ("data", "model"))
-    print(f"ROW,fig11/cc/DM,{us:.1f},rounds={rounds};"
-          f"sync_bytes={rounds*label_bytes*8}")
+    emit("fig11/cc/DM", us, f"rounds={rounds};"
+         f"sync_bytes={rounds*label_bytes*8}")
 """)
 
 

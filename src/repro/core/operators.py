@@ -29,7 +29,9 @@ Every relaxation op lowers through a selectable **substrate**:
 * ``"jnp"``    — generic XLA scatter / sorted segment ops
   (``kernels/graph_ops/ref.py``, the reference semantics);
 * ``"pallas"`` — the blocked Pallas kernels in ``kernels/graph_ops/``
-  (``interpret=True`` on CPU; real lowering on accelerators).
+  (``interpret=True`` on CPU).  The TPU compiler refuses all four kernels
+  today (``tests/test_tpu_compile.py``), so on a TPU this substrate fails
+  with the compiler's error; ``"jnp"`` is the path that runs there.
 
 Select globally with ``set_substrate("pallas")`` / the ``substrate_scope``
 context manager, or per call via the ``substrate=`` argument.  The

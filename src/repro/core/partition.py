@@ -25,7 +25,6 @@ Edge shards are padded to equal length per device (SPMD static shapes).
 from __future__ import annotations
 
 import dataclasses
-import inspect
 from functools import partial
 from typing import Callable, Tuple
 
@@ -37,20 +36,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .graph import Graph, round_up
 from . import operators as ops
 from . import placement as pl
-
-# shard_map moved from jax.experimental to the jax namespace (and the
-# replication-check kwarg was renamed check_rep -> check_vma along the way);
-# resolve both at import so the BSP engine runs on either API generation.
-try:
-    _shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental namespace only
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SM_CHECK_KWARG = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
 
 
 @jax.tree_util.register_dataclass
@@ -109,7 +94,8 @@ def _assemble(shards, n, n_pad, out_deg, scheme, policy, rows, cols,
     RP = np.zeros((ndev, n_pad + 1), np.int32)
     DEG = np.zeros((ndev, n_pad), np.int32)
     for i, (s, d, w) in enumerate(shards):
-        order = np.lexsort((d, s))  # shard-local CSR order
+        # shard-local CSR order: (s, d)-sorted, as one int64 key
+        order = np.argsort(s.astype(np.int64) * n_pad + d, kind="stable")
         s, d, w = s[order], d[order], w[order]
         S[i, : len(s)] = s
         D[i, : len(d)] = d
@@ -222,12 +208,12 @@ def make_bsp_step(
             new = labels + acc
         return new, ops.updated_mask(labels, new)
 
-    smapped = _shard_map(
+    smapped = jax.shard_map(
         local_round,
         mesh=mesh,
         in_specs=(P(), P(), P(axes), P(axes), P(axes)),
         out_specs=(P(), P()),
-        **{_SM_CHECK_KWARG: False},
+        check_vma=False,
     )
 
     @jax.jit

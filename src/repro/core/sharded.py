@@ -85,8 +85,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..kernels import graph_ops as gk
 from .frontier import SparseFrontier, compact_local
 from .graph import Graph
-from .partition import (_SM_CHECK_KWARG, _shard_map, PartitionedGraph,
-                        partition_1d, partition_2d)
+from .partition import PartitionedGraph, partition_1d, partition_2d
 from . import placement as pl
 
 
@@ -262,10 +261,10 @@ def _edge_scatter(mesh, axes, red, e_src, e_dst, e_w, src_val, mask, out_init,
         return _merge(out0, red.reduce(acc, kind), kind)
 
     mask_spec = P() if vertex_mask else P(axes)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), mask_spec, P(), P(axes), P(axes), P(axes)),
-        out_specs=P(), **{_SM_CHECK_KWARG: False},
+        out_specs=P(), check_vma=False,
     )
     return fn(src_val, mask, out_init, e_src, e_dst, e_w)
 
@@ -496,10 +495,10 @@ class ShardedGraph:
             acc = jax.vmap(lane)(vals, msk, out0)
             return _merge(out0, _cross_reduce(acc, axes, kind), kind)
 
-        fn = _shard_map(
+        fn = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(P(), P(), P(), P(axes), P(axes), P(axes)),
-            out_specs=P(), **{_SM_CHECK_KWARG: False},
+            out_specs=P(), check_vma=False,
         )
         return fn(src_val, active, out_init, self.src, self.dst, self.w)
 
@@ -578,10 +577,10 @@ class ShardedGraph:
                 c = gk.intersect_ref(a, s, d, sent)
             return jax.lax.psum(jnp.asarray(c, jnp.int32), axes)
 
-        fn = _shard_map(
+        fn = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(P(), P(axes), P(axes)),
-            out_specs=P(), **{_SM_CHECK_KWARG: False},
+            out_specs=P(), check_vma=False,
         )
         return fn(adj, osrc, odst)
 
@@ -606,11 +605,11 @@ class ShardedGraph:
             t = jnp.asarray(t, jnp.int32).reshape(1)
             return s[None], d[None], ww[None], v[None], t
 
-        fn = _shard_map(
+        fn = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(P(), P(), P(self.axes), P(self.axes), P(self.axes),
                       P(self.axes)),
-            out_specs=(P(self.axes),) * 5, **{_SM_CHECK_KWARG: False},
+            out_specs=(P(self.axes),) * 5, check_vma=False,
         )
         s, d, w, v, totals = fn(f.idx, f.count, self.shard_deg,
                                 self.shard_row_ptr, self.dst, self.w)
@@ -674,11 +673,11 @@ class ShardedGraph:
             acc = jax.lax.cond(esc, dense_branch, sparse_branch, None)
             return _merge(out0, red.reduce(acc, kind), kind), n_esc
 
-        fn = _shard_map(
+        fn = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(P(), P(), P(), P(axes), P(axes), P(axes), P(axes),
                       P(axes)),
-            out_specs=(P(), P()), **{_SM_CHECK_KWARG: False},
+            out_specs=(P(), P()), check_vma=False,
         )
         return fn(src_val, mask, out_init, self.shard_deg,
                   self.shard_row_ptr, self.src, self.dst, self.w)

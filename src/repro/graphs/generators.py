@@ -45,10 +45,14 @@ def rmat(
     abc = a + b + c
     for bit in range(scale):
         r = rng.random(m)
-        go_right = (r > a) & (r <= ab) | (r > abc)
+        # quadrant of this bit: (a, ab] and (abc, 1) go right, (ab, 1) down
         go_down = r > ab
-        src = src * 2 + go_down.astype(np.int64)
-        dst = dst * 2 + go_right.astype(np.int64)
+        go_right = (r > a) & ~go_down
+        go_right |= r > abc
+        src <<= 1               # in place: at scale 22 each pass is 0.5 GB
+        src |= go_down
+        dst <<= 1
+        dst |= go_right
     src, dst = _dedup(src, dst, n)
     return src, dst, n
 

@@ -33,9 +33,10 @@ term, so min/max/or results are bitwise identical to the jnp substrate and
 add differs only by float summation order (exact on integer-valued data —
 what the parity suite pins down).
 
-On CPU the kernels run under ``interpret=True`` (the correctness path, like
-every other kernel package here); Mosaic lowering of the in-kernel
-gather/scatter is the recorded follow-up in the package README.
+On CPU the kernels run under ``interpret=True`` (the correctness path).
+The TPU compiler refuses all four (the ``(1, block_e)`` tiles, then the
+in-kernel 1-D gather and scatter-min); the package README lists the
+refusals and ``tests/test_tpu_compile.py`` pins them.
 """
 
 from __future__ import annotations

@@ -1,8 +1,14 @@
-"""Public wrappers for the graph_ops Pallas kernels: jit caching, CPU
-``interpret=True`` fallback, block-size auto-pick, and bool→uint8 widening
-for the ``or`` reduction.  ``core/operators.py`` routes here when the
+"""Public wrappers for the graph_ops Pallas kernels: jit caching, the
+interpret-mode choice, block-size auto-pick, and bool→uint8 widening for
+the ``or`` reduction.  ``core/operators.py`` routes here when the
 ``"pallas"`` substrate is selected; callers that want the raw kernels can
-use these directly with arrays."""
+use these directly with arrays.
+
+Interpret mode is taken only on the CPU backend.  Everywhere else the
+kernels go to the TPU compiler, and a kernel it refuses fails with the
+compiler's own error: there is no quiet fallback to the interpreter or to
+the ``"jnp"`` substrate.  (Today the TPU compiler refuses all four
+kernels; ``tests/test_tpu_compile.py`` pins the refusals.)"""
 
 from __future__ import annotations
 
@@ -19,16 +25,9 @@ from .graph_ops import advance_pallas, edge_relax_pallas, intersect_pallas
 _BLOCK_TARGET = 1024
 
 
-def _attempt_lowering() -> bool:
-    """Only TPU attempts real (Mosaic) lowering — these are pltpu kernels
-    (VMEM scratch, sequential revisited-output grid), so GPU/CPU always
-    interpret.  TPU lowering is itself unvalidated (see README follow-ups);
-    pass ``interpret=True`` explicitly to override a compile failure there.
-    """
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
+def _interpret() -> bool:
+    """Interpret the kernels on the CPU backend only."""
+    return jax.default_backend() == "cpu"
 
 
 def _pick_block(size: int, target: int = _BLOCK_TARGET) -> int:
@@ -63,7 +62,7 @@ def edge_relax(src, dst, w, mask, src_val, out_init, *, kind: str = "min",
     else a per-edge validity mask aligned with ``src`` (batch relax).
     """
     if interpret is None:
-        interpret = not _attempt_lowering()
+        interpret = _interpret()
     if block_e is None:
         block_e = _pick_block(src.shape[0])
     return _edge_relax_jit(src, dst, w, mask, src_val, out_init, kind,
@@ -89,7 +88,7 @@ def advance_frontier(f_idx, f_count, out_deg, row_ptr, col_idx, edge_w, *,
     """Merge-path frontier expansion into ``budget`` edge slots; returns
     ``(src, dst, w, valid, total)``."""
     if interpret is None:
-        interpret = not _attempt_lowering()
+        interpret = _interpret()
     if block_b is None:
         block_b = _pick_block(budget)
     return _advance_jit(f_idx, f_count, out_deg, row_ptr, col_idx, edge_w,
@@ -110,7 +109,7 @@ def intersect_count(adj, src, dst, *, sentinel: int,
     """Blocked oriented-intersection count for a batch of oriented edges
     (see graph_ops.py); returns an exact int32 scalar."""
     if interpret is None:
-        interpret = not _attempt_lowering()
+        interpret = _interpret()
     if block_e is None:
         block_e = _pick_block(src.shape[0])
     return _intersect_jit(adj, src, dst, sentinel, block_e, interpret)
