@@ -15,7 +15,9 @@ iteration for PageRank).
                                         # mesh, against one device
 
 Each phase prints one JSON line. Its ``compile_s`` / ``run_s`` are smoke
-timings of one cold run, not benchmark numbers. The last line is
+timings of one cold run, not benchmark numbers; ``slowest_compiles`` names
+the programs that took longest to compile. Progress goes to standard
+error, each line with the seconds since the start. The last line is
 ``{"ok": true, "device": {...}}``. A phase that fails raises, and the
 script exits non-zero. Without a TPU the script exits non-zero before any
 phase and prints no result.
@@ -58,8 +60,10 @@ ZIPF_A = 1.5        # source popularity skew of the served queries
 INSERTS = 4096      # edges in the dynamic phase's insert batch
 # Rounds of the streamed PageRank. Its live set is every shard, so with a
 # pool of 4 of 16 shards each round re-reads (and re-checksums) the whole
-# CSR; it is compared with the resident run over the same rounds.
-PR_STREAMED_ROUNDS = 12
+# CSR, every shard padded to the largest (a third of the edges: rmat does
+# not relabel vertices), about 23 s a round on a v5e host. It is compared
+# with the resident push over the same CSR and rounds.
+PR_STREAMED_ROUNDS = 4
 
 # lowering and XLA compile; tracing is left out because nested jits report
 # nested (overlapping) trace events
@@ -73,14 +77,14 @@ class PhaseClock:
 
     def __init__(self):
         self.compile_s = 0.0
-        self.backend_compiles = []
+        self.backend_compiles = []   # (seconds, program name)
         jax.monitoring.register_event_duration_secs_listener(self._on_event)
 
-    def _on_event(self, name, secs, **_):
+    def _on_event(self, name, secs, fun_name="?", **_):
         if name in _COMPILE_EVENTS:
             self.compile_s += secs
         if name == _COMPILE_EVENTS[-1]:
-            self.backend_compiles.append(secs)
+            self.backend_compiles.append((secs, fun_name))
 
     def close(self):
         jax.monitoring.unregister_event_duration_listener(self._on_event)
@@ -100,10 +104,20 @@ class PhaseClock:
     def timing(self):
         """Compile and run seconds of the last ``with`` block, and how many
         programs it compiled (and how many of those in under a second)."""
+        slowest = sorted(self.programs, reverse=True)[:3]
         return {"timing": "smoke: one cold run, not a benchmark",
                 "compile_s": self.compile, "run_s": self.wall - self.compile,
                 "programs": len(self.programs),
-                "programs_under_1s": sum(s < 1.0 for s in self.programs)}
+                "programs_under_1s": sum(s < 1.0 for s, _ in self.programs),
+                "slowest_compiles": [[name, s] for s, name in slowest]}
+
+
+_T0 = time.perf_counter()
+
+
+def progress(what: str) -> None:
+    print(f"chip_smoke: [{time.perf_counter() - _T0:8.1f} s] {what}",
+          file=sys.stderr, flush=True)
 
 
 def emit(record: dict) -> None:
@@ -145,7 +159,7 @@ class Workload:
     n: int
     g: object          # weighted CSR + CSC (SSSP)
     gu: object         # same edges, unit weights (BFS, tiered, serve, dynamic)
-    gsym: object       # symmetrized, unweighted (CC, PageRank)
+    gsym: object       # symmetrized, unweighted (CC, PageRank), + CSC
     a: sp.csr_matrix   # weighted directed adjacency
     asym: sp.csr_matrix
     source: int
@@ -162,14 +176,23 @@ def unit_weights(g):
     return dataclasses.replace(g, **kw)
 
 
+def csr_only(g):
+    """``g`` without its CSC mirror: dense pushes then scatter over the
+    CSR."""
+    return dataclasses.replace(g, in_row_ptr=None, in_col_idx=None,
+                               in_src_idx=None, in_edge_w=None, in_deg=None)
+
+
 def build_workload(scale: int, seed: int, csc: bool = True) -> Workload:
+    progress(f"building graph500-{scale}")
     t0 = time.perf_counter()
     src, dst, n = gen.rmat(scale, EDGE_FACTOR, seed)
     w = gen.random_weights(len(src), seed=seed + 1)
     g = from_coo(src, dst, n, w, build_csc=csc)
-    gsym = from_coo(src, dst, n, symmetrize=True)
+    gsym = from_coo(src, dst, n, symmetrize=True, build_csc=csc)
     jax.block_until_ready((g, gsym))
     build_s = time.perf_counter() - t0
+    progress(f"graph on the device ({build_s:.1f} s); host references next")
     # rmat output is already free of self-loops and duplicates, so scipy's
     # duplicate summing cannot alter a weight here
     a = sp.csr_matrix((w.astype(np.float64), (src, dst)), shape=(n, n))
@@ -268,18 +291,22 @@ def phase_resident(wl: Workload, device, clock: PhaseClock) -> dict:
            "host_build_s": wl.build_s, "source": s}
     _require(out, _on_device((wl.g, wl.gu, wl.gsym), device),
              f"graph arrays not all on {device}")
+    progress("resident: BFS")
     with clock:
         dist, st = bfs.bfs_dd_sparse(wl.gu, s)
         dist = jax.block_until_ready(dist)
+    progress("resident: BFS reference")
     hops = ref_hops(wl.a, [s])[0]
     ok = _hops_equal(dist, hops)
     out["bfs"] = dict(clock.timing(), stats=_stats(st), exact=ok,
                       reached=int(np.isfinite(hops).sum()))
     _require(out, ok, "resident BFS hops differ from scipy")
 
+    progress("resident: SSSP")
     with clock:
         sd, st = sssp.sssp_delta(wl.g, s, delta=4.0)
         sd = np.asarray(jax.block_until_ready(sd))[: wl.n]
+    progress("resident: SSSP reference")
     ref = csgraph.dijkstra(wl.a, indices=s)
     got = np.where(sd >= float(sssp.INF), np.inf, sd.astype(np.float64))
     fin = np.isfinite(ref)
@@ -291,6 +318,7 @@ def phase_resident(wl: Workload, device, clock: PhaseClock) -> dict:
     _require(out, same_reach and err <= SSSP_RTOL,
              f"SSSP differs from float64 Dijkstra (max rel err {err})")
 
+    progress("resident: CC")
     with clock:
         lab, st = cc.cc_pointer_jump(wl.gsym)
         lab = jax.block_until_ready(lab)
@@ -300,9 +328,11 @@ def phase_resident(wl: Workload, device, clock: PhaseClock) -> dict:
                      components=int(ncomp))
     _require(out, ok, "CC partition differs from scipy")
 
+    progress("resident: PageRank")
     with clock:
         rank, st = pagerank.pr_push(wl.gsym, damping=DAMPING, tol=PR_TOL)
         rank = jax.block_until_ready(rank)
+    progress("resident: PageRank reference")
     chk = _pagerank_check(rank, st.rounds, wl.asym)
     out["pagerank"] = dict(clock.timing(), stats=_stats(st), tol=PR_TOL,
                            **chk)
@@ -319,6 +349,7 @@ def phase_tiered(wl: Workload, resident: dict, device, clock: PhaseClock,
     and require the streamed answers to match the resident ones."""
     out = {"phase": "tiered", "n": wl.n, "nshards": nshards,
            "resident_shards": resident_shards}
+    progress("tiered: BFS")
     tg = tier_graph(wl.gu, nshards=nshards, resident_shards=resident_shards)
     out["bfs_graph"] = {"csr_bytes": tg.csr_bytes,
                         "pool_bytes": tg.resident_budget,
@@ -333,6 +364,7 @@ def phase_tiered(wl: Workload, resident: dict, device, clock: PhaseClock,
              f"BFS did not stream (h2d={st.h2d_bytes}, "
              f"shards={st.shards_streamed})")
 
+    progress("tiered: PageRank")
     tsym = tier_graph(wl.gsym, nshards=nshards,
                       resident_shards=resident_shards)
     with clock:
@@ -340,8 +372,11 @@ def phase_tiered(wl: Workload, resident: dict, device, clock: PhaseClock,
                                     max_iters=PR_STREAMED_ROUNDS)
         rank = np.asarray(jax.block_until_ready(rank))
     del tsym
-    want, _ = pagerank.pr_push(wl.gsym, damping=DAMPING, tol=PR_TOL,
-                               max_iters=PR_STREAMED_ROUNDS)
+    # the resident push over the CSR alone (no CSC mirror), as streamed:
+    # with the mirror it would reduce over sorted in-edges in a tree, whose
+    # float sums differ from a scatter's by more than association per shard
+    want, _ = pagerank.pr_push(csr_only(wl.gsym), damping=DAMPING,
+                               tol=PR_TOL, max_iters=PR_STREAMED_ROUNDS)
     want = np.asarray(want)
     diff = float(np.max(np.abs(rank - want)))
     # float adds associate per shard when streamed (tiered.py's contract)
@@ -369,11 +404,13 @@ def zipf_sources(wl: Workload, count: int, seed: int) -> np.ndarray:
 
 def phase_serve(wl: Workload, device, clock: PhaseClock, seed: int) -> dict:
     sources = zipf_sources(wl, SERVE_REQUESTS, seed + 3)
+    progress("serve")
     server = GraphServer(wl.gu, algo="bfs", max_batch=SERVE_BATCH)
     reqs = [QueryRequest(rid=i, source=int(s), arrive_round=i // 4)
             for i, s in enumerate(sources)]
     with clock:
         done = server.serve(reqs)
+    progress("serve: references")
     uniq = np.unique(sources)
     ref = dict(zip(uniq.tolist(), ref_hops(wl.a, uniq)))
     exact = [r.reject_reason is None and _hops_equal(r.labels, ref[r.source])
@@ -393,6 +430,7 @@ def phase_dynamic(wl: Workload, tg, resident: dict, device,
                   clock: PhaseClock, seed: int) -> dict:
     """One seeded insert batch; the incremental BFS must equal a
     from-scratch BFS on the updated graph bitwise, and scipy's BFS."""
+    progress("dynamic")
     dyn = dynamize(tg)
     rng = np.random.default_rng(seed + 4)
     bsrc = rng.integers(0, wl.n, INSERTS)
@@ -406,6 +444,7 @@ def phase_dynamic(wl: Workload, tg, resident: dict, device,
         scratch, st_scr = bfs.bfs_dd_sparse(dyn, wl.source)
         scratch = jax.block_until_ready(scratch)
     bitwise = bool(np.array_equal(np.asarray(inc), np.asarray(scratch)))
+    progress("dynamic: reference")
     upd = wl.a + sp.csr_matrix(
         (np.ones(len(delta.src)), (delta.src, delta.dst)), shape=wl.a.shape)
     exact = _hops_equal(inc, ref_hops(upd, [wl.source])[0])
@@ -425,6 +464,7 @@ def phase_four_chips(wl: Workload, devices, clock: PhaseClock) -> dict:
     4-device mesh, against scipy and against one device."""
     from jax.sharding import Mesh
     mesh = Mesh(np.asarray(devices[:4]), ("data",))
+    progress("four chips: sharding")
     sg = shard_graph(wl.gu, mesh)
     ssym = shard_graph(wl.gsym, mesh)
     homes = sorted({sh.device.id for sh in sg.src.addressable_shards})
@@ -432,6 +472,7 @@ def phase_four_chips(wl: Workload, devices, clock: PhaseClock) -> dict:
            "ndev": sg.ndev, "epd": sg.epd, "reducer": sg.red.mode,
            "edge_shard_devices": homes}
     _require(out, len(homes) == 4, f"edge shards sit on devices {homes}")
+    progress("four chips: BFS")
     with clock:
         dist4, st = bfs.bfs_dd_sparse(sg, wl.source)
         dist4 = jax.block_until_ready(dist4)
@@ -445,6 +486,7 @@ def phase_four_chips(wl: Workload, devices, clock: PhaseClock) -> dict:
                       exact=exact, one_device=clock.timing())
     _require(out, bitwise, "4-device BFS is not bitwise equal to one device")
     _require(out, exact, "4-device BFS differs from scipy")
+    progress("four chips: PageRank")
     with clock:
         rank, st = pagerank.pr_push(ssym, damping=DAMPING, tol=PR_TOL)
         rank = jax.block_until_ready(rank)

@@ -23,6 +23,7 @@ from .. import operators as ops
 from ..engine import (SparseLadderEngine, RunStats, run_dense,
                       run_streamed, _mask_cond, _mask_active)
 from ..graph import Graph
+from ...kernels.graph_ops.ref import scatter_at
 
 INF = jnp.float32(jnp.finfo(jnp.float32).max)
 
@@ -170,7 +171,8 @@ def _in_degrees(g) -> jax.Array:
         return in_deg
     idst = getattr(g, "in_dst", None)
     idst = g.in_src_idx if idst is None else idst.reshape(-1)
-    counted = jnp.zeros((g.n_pad,), jnp.int32).at[idst].add(1)
+    counted = scatter_at(jnp.zeros((g.n_pad,), jnp.int32), idst,
+                         jnp.ones(idst.shape, jnp.int32), "add")
     return counted.at[g.sentinel].set(0)
 
 

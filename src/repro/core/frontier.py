@@ -12,7 +12,8 @@ exist.  We adapt the idea with two constructions:
   the fallback.
 
 * ``SparseFrontier`` — a fixed-``capacity`` buffer of vertex indices plus a
-  ``count``.  Compaction uses ``jnp.nonzero(..., size=capacity)``.  Work per
+  ``count``.  Compaction is a prefix sum and a scatter
+  (``jnp.nonzero(..., size=capacity)``'s result).  Work per
   round is O(capacity), *not* O(n) or O(m).  Capacities come from a geometric
   **ladder** (powers of ``ladder_base`` × block_size): each distinct capacity
   is one compiled executable, so the number of recompilations over a whole run
@@ -30,6 +31,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from ..kernels.graph_ops.ref import compact_indices, scatter_at
 from .graph import Graph
 
 
@@ -79,7 +81,9 @@ class SparseFrontier:
 
 
 def dense_from_indices(indices, n_pad: int) -> DenseFrontier:
-    mask = jnp.zeros((n_pad,), bool).at[jnp.asarray(indices)].set(True)
+    indices = jnp.asarray(indices)
+    mask = scatter_at(jnp.zeros((n_pad,), bool), indices,
+                      jnp.ones(indices.shape, bool), "set")
     # never activate the sentinel
     mask = mask.at[n_pad - 1].set(False)
     return DenseFrontier(mask=mask)
@@ -89,8 +93,8 @@ def compact(mask: jax.Array, capacity: int, sentinel: int) -> SparseFrontier:
     """Dense mask → sparse worklist with static capacity."""
     mask = mask.at[sentinel].set(False)
     count = jnp.sum(mask.astype(jnp.int32))
-    (idx,) = jnp.nonzero(mask, size=capacity, fill_value=sentinel)
-    return SparseFrontier(idx=idx.astype(jnp.int32), count=count, sentinel=sentinel)
+    idx = compact_indices(mask, capacity, sentinel)
+    return SparseFrontier(idx=idx, count=count, sentinel=sentinel)
 
 
 def compact_local(mask: jax.Array, deg: jax.Array, capacity: int,
@@ -103,8 +107,7 @@ def compact_local(mask: jax.Array, deg: jax.Array, capacity: int,
     exceed ``capacity``, which is the shard's overflow signal."""
     m = (mask & (deg > 0)).at[sentinel].set(False)
     count = jnp.sum(m.astype(jnp.int32))
-    (idx,) = jnp.nonzero(m, size=capacity, fill_value=sentinel)
-    return idx.astype(jnp.int32), count
+    return compact_indices(m, capacity, sentinel), count
 
 
 def ladder_capacities(n_pad: int, block_size: int, base: int = 4) -> Tuple[int, ...]:

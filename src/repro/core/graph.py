@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.graph_ops.ref import scatter_at
+
 
 def _pad_to(x: np.ndarray, size: int, fill) -> np.ndarray:
     if x.shape[0] == size:
@@ -194,30 +196,35 @@ def from_coo(
         deg = counts
         return rp, ci, si, ew, deg
 
-    rp, ci, si, ew, deg = build(src, dst)
+    csr = tuple(jnp.asarray(a) for a in build(src, dst))
     kwargs = {}
     if build_csc:
-        irp, isi_dst, isrc, iew, ideg = build(dst, src)
+        # a symmetrized, deduplicated edge set (with its symmetric minimum
+        # weights) sorts the same by (dst, src) as by (src, dst): its CSC
+        # is its CSR, and shares the device arrays
+        csc = csr if symmetrize and dedup else tuple(
+            jnp.asarray(a) for a in build(dst, src))
         # for CSC: "row" is the destination, the stored index is the source
         kwargs = dict(
-            in_row_ptr=jnp.asarray(irp),
-            in_col_idx=jnp.asarray(isi_dst),   # in-neighbour (original src)
-            in_src_idx=jnp.asarray(isrc),      # the destination vertex per in-edge
-            in_edge_w=jnp.asarray(iew),
-            in_deg=jnp.asarray(ideg),
+            in_row_ptr=csc[0],
+            in_col_idx=csc[1],   # in-neighbour (original src)
+            in_src_idx=csc[2],   # the destination vertex per in-edge
+            in_edge_w=csc[3],
+            in_deg=csc[4],
         )
 
+    rp, ci, si, ew, deg = csr
     return Graph(
         n=n,
         m=m,
         n_pad=n_pad,
         m_pad=m_pad,
         block_size=block_size,
-        row_ptr=jnp.asarray(rp),
-        col_idx=jnp.asarray(ci),
-        src_idx=jnp.asarray(si),
-        edge_w=jnp.asarray(ew),
-        out_deg=jnp.asarray(deg),
+        row_ptr=rp,
+        col_idx=ci,
+        src_idx=si,
+        edge_w=ew,
+        out_deg=deg,
         **kwargs,
     )
 
@@ -235,4 +242,5 @@ def to_dense(g: Graph) -> np.ndarray:
 
 @partial(jax.jit, static_argnames=("n_pad",))
 def degrees_from_edges(src: jax.Array, n_pad: int) -> jax.Array:
-    return jnp.zeros((n_pad,), jnp.int32).at[src].add(1)
+    return scatter_at(jnp.zeros((n_pad,), jnp.int32), src,
+                      jnp.ones(src.shape, jnp.int32), "add")

@@ -26,8 +26,9 @@ the destination, scatter into the source) — bc's backward dependency sweep
 
 Every relaxation op lowers through a selectable **substrate**:
 
-* ``"jnp"``    — generic XLA scatter / sorted segment ops
-  (``kernels/graph_ops/ref.py``, the reference semantics);
+* ``"jnp"``    — XLA scatter and sorted segmented scans
+  (``kernels/graph_ops/ref.py``, the reference semantics); its dense
+  push reduces over the CSC mirror where there is one;
 * ``"pallas"`` — the blocked Pallas kernels in ``kernels/graph_ops/``
   (``interpret=True`` on CPU).  The TPU compiler refuses all four kernels
   today (``tests/test_tpu_compile.py``), so on a TPU this substrate fails
@@ -195,7 +196,25 @@ def push_dense(
             s, d, g.edge_w, active, src_val, out_init,
             kind=kind, use_weight=use_weight, vertex_mask=True,
         )
-    return gk.push_ref(s, d, g.edge_w, src_val, active,
+    return _push_sorted(g, src_val, active, out_init, kind, use_weight,
+                        reverse)
+
+
+def _push_sorted(g, src_val, active, out_init, kind, use_weight, reverse):
+    """The jnp substrate's dense push as a reduction over destination-
+    sorted edges: the CSR itself when reversed, else the CSC mirror, with a
+    scatter over the CSR only where there is none.  On a TPU v5e, 64M
+    messages into graph500-22's 4.2M vertices take 0.87 s as an unsorted
+    scatter and 0.13 s as ``graph_ops.sorted_segment_reduce``.  Both relax
+    the same message multiset, so min/max/or agree bitwise and add up to
+    float association."""
+    if reverse:
+        return gk.pull_ref(g.col_idx, g.src_idx, g.edge_w, src_val, active,
+                           out_init, kind, use_weight, g.row_ptr)
+    if getattr(g, "has_csc", False):
+        return gk.pull_ref(g.in_col_idx, g.in_src_idx, g.in_edge_w, src_val,
+                           active, out_init, kind, use_weight, g.in_row_ptr)
+    return gk.push_ref(g.src_idx, g.col_idx, g.edge_w, src_val, active,
                        out_init, kind, use_weight)
 
 
@@ -209,9 +228,9 @@ def pull_dense(
     substrate: str | None = None,
 ) -> jax.Array:
     """Pull-style relax over in-edges: each vertex reduces over its
-    in-neighbours.  Requires CSC.  The jnp substrate uses sorted segment ops
-    (in-edges are grouped by destination, ``indices_are_sorted=True``); the
-    Pallas substrate walks the same dst-sorted edge blocks."""
+    in-neighbours.  Requires CSC.  The jnp substrate reduces the
+    destination-sorted in-edges with ``graph_ops.sorted_segment_reduce``;
+    the Pallas substrate walks the same dst-sorted edge blocks."""
     sub = _resolve(substrate)
     tiered = getattr(g, "tiered_pull_dense", None)
     if tiered is not None:
@@ -241,7 +260,7 @@ def pull_dense(
             out_init, kind=kind, use_weight=use_weight, vertex_mask=True,
         )
     return gk.pull_ref(g.in_col_idx, g.in_src_idx, g.in_edge_w, src_val,
-                       active, out_init, kind, use_weight)
+                       active, out_init, kind, use_weight, g.in_row_ptr)
 
 
 @jax.tree_util.register_dataclass
@@ -357,6 +376,13 @@ def batched_push_dense(
             lambda v, a, o: gk.edge_relax(
                 g.src_idx, g.col_idx, g.edge_w, a, v, o,
                 kind=kind, use_weight=use_weight, vertex_mask=True)
+        )(src_val, active, out_init)
+    if getattr(g, "has_csc", False):
+        # destination-sorted in-edges, as in the single-lane dense push
+        return jax.vmap(
+            lambda v, a, o: gk.pull_ref(g.in_col_idx, g.in_src_idx,
+                                        g.in_edge_w, v, a, o, kind,
+                                        use_weight, g.in_row_ptr)
         )(src_val, active, out_init)
     return gk.batched_push_ref(g.src_idx, g.col_idx, g.edge_w, src_val,
                                active, out_init, kind, use_weight)

@@ -10,7 +10,6 @@ from .ref import (  # noqa: F401
     advance_ref,
     batched_push_ref,
     batched_relax_ref,
-    batched_scatter_reduce,
     det_push_ref,
     det_relax_ref,
     det_scatter_add,
@@ -21,4 +20,5 @@ from .ref import (  # noqa: F401
     relax_ref,
     scatter_reduce,
     sorted_lower_bound,
+    sorted_segment_reduce,
 )

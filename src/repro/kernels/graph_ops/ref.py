@@ -42,26 +42,78 @@ def neutral_for(kind: str, dtype) -> jax.Array:
     raise ValueError(kind)
 
 
+# ---------------------------------------------------------------------------
+# Scatters and compaction into vertex-sized arrays
+# ---------------------------------------------------------------------------
+# The TPU compiler keeps a scatter's operand in on-chip memory only up to a
+# few million elements (3,145,728 compiles as such; 4,193,280 does not).
+# Above that it sorts the updates first, and a program holding that sort
+# takes 7-13 s to compile (graph500-22: n_pad = 4,194,816), against under
+# a second for the same scatter split over operand ranges.  It sorts the
+# updates of every 8- or 16-bit scatter, whatever its size, so those
+# scatter in int32.  Its cumsum over a vertex array or a large frontier
+# is likewise 4-20 s to compile, against under one when blocked.
+# ``tests/test_tpu_compile.py`` pins all three.
+SCATTER_CHUNK = 3 << 20   # most operand elements one scatter may hold
+_SCAN_BLOCK = 1024
+
+
+def scatter_at(out, idx, upd, op: str):
+    """``getattr(out.at[..., idx], op)(upd)`` along the last axis of a 1-D
+    or 2-D ``out``, in as few operand ranges of at most ``SCATTER_CHUNK``
+    elements as it takes.  Each range sees every update, with the indices
+    outside it dropped, so every element receives the same updates in the
+    same order as one whole scatter."""
+    if out.dtype.itemsize < 4 and not jnp.issubdtype(out.dtype, jnp.floating):
+        wide = scatter_at(out.astype(jnp.int32), idx, upd.astype(jnp.int32),
+                          op)
+        return wide.astype(out.dtype)
+    k = -(-out.size // SCATTER_CHUNK)
+    if k <= 1:
+        return getattr(out.at[..., idx], op)(upd)
+    if out.ndim == 2:
+        # one row (lane) at a time: each update is then visited once per
+        # range of its own row, not once per range of the whole matrix
+        return jnp.stack([scatter_at(out[r], idx, upd[r], op)
+                          for r in range(out.shape[0])])
+    n = out.shape[-1]
+    size = -(-n // k)
+    parts = []
+    for lo in range(0, n, size):
+        hi = min(lo + size, n)
+        local = jnp.where((idx >= lo) & (idx < hi), idx - lo, hi - lo)
+        parts.append(getattr(out[..., lo:hi].at[..., local], op)(
+            upd, mode="drop"))
+    return jnp.concatenate(parts, axis=-1)
+
+
+def prefix_sum(x):
+    """Inclusive cumulative sum of a 1-D array, scanned in blocks."""
+    n = x.shape[0]
+    rows = jnp.pad(x, (0, -n % _SCAN_BLOCK)).reshape(-1, _SCAN_BLOCK)
+    inner = jnp.cumsum(rows, axis=1)
+    tot = inner[:, -1]
+    return (inner + (jnp.cumsum(tot) - tot)[:, None]).reshape(-1)[:n]
+
+
+def compact_indices(mask, capacity: int, fill: int):
+    """``jnp.nonzero(mask, size=capacity, fill_value=fill)[0]`` as int32:
+    the first ``capacity`` set positions in ascending order, then
+    ``fill``."""
+    slot = jnp.where(mask, prefix_sum(mask.astype(jnp.int32)) - 1, capacity)
+    out = jnp.full((capacity,), fill, jnp.int32)
+    return scatter_at(out, slot, jnp.arange(mask.shape[0], dtype=jnp.int32),
+                      "set")
+
+
 def scatter_reduce(dst, msg, out, kind: str):
     """Reduce ``msg`` into ``out`` at positions ``dst``."""
-    ref = out.at[dst]
-    if kind == "min":
-        return ref.min(msg)
-    if kind == "max":
-        return ref.max(msg)
-    if kind == "add":
-        return ref.add(msg)
+    if kind in ("min", "max", "add"):
+        return scatter_at(out, dst, msg, kind)
     if kind == "or":
-        if out.dtype == bool:
-            # scatter-max over uint8: duplicate destinations OR together
-            # (scatter-set would pick an arbitrary duplicate)
-            return (
-                out.astype(jnp.uint8)
-                .at[dst]
-                .max(msg.astype(jnp.uint8))
-                .astype(bool)
-            )
-        return ref.max(msg.astype(out.dtype))
+        # scatter-max: duplicate destinations OR together (scatter-set
+        # would pick an arbitrary duplicate)
+        return scatter_at(out, dst, msg.astype(out.dtype), "max")
     raise ValueError(kind)
 
 
@@ -99,7 +151,7 @@ def det_scatter_add(dst, msg, out):
     # last slot of each run holds the segment sum; everything else adds the
     # exact zero of the dtype, which cannot perturb the result
     is_tail = jnp.concatenate([seg[1:] != seg[:-1], jnp.ones((1,), bool)])
-    return out.at[seg].add(jnp.where(is_tail, val, zero))
+    return scatter_at(out, seg, jnp.where(is_tail, val, zero), "add")
 
 
 def det_push_ref(src, dst, w, src_val, active, out_init,
@@ -132,26 +184,68 @@ def push_ref(src, dst, w, src_val, active, out_init, kind: str = "min",
     return scatter_reduce(dst, msg, out_init, kind)
 
 
+_COMBINE = {"min": jnp.minimum, "max": jnp.maximum, "add": jnp.add,
+            "or": jnp.maximum}
+
+
 def pull_ref(nbr, dst, w, src_val, active, out_init, kind: str = "min",
-             use_weight: bool = True):
+             use_weight: bool = True, row_ptr=None):
     """Pull over in-edges grouped by destination (``dst`` sorted ascending):
-    sorted segment reduction merged into ``out_init``."""
-    v = src_val[nbr]
-    msg = edge_message(v, w, kind, use_weight)
+    ``sorted_segment_reduce`` merged into ``out_init``.  With the edges'
+    ``row_ptr`` each vertex's result is gathered, else scattered."""
     neutral = neutral_for(kind, out_init.dtype)
-    msg = jnp.where(active[nbr], msg.astype(out_init.dtype), neutral)
-    seg = dict(num_segments=out_init.shape[0], indices_are_sorted=True)
-    if kind == "min":
-        return jnp.minimum(out_init, jax.ops.segment_min(msg, dst, **seg))
-    if kind == "max":
-        return jnp.maximum(out_init, jax.ops.segment_max(msg, dst, **seg))
-    if kind == "add":
-        return out_init + jax.ops.segment_sum(msg, dst, **seg)
-    if kind == "or":
-        red = jax.ops.segment_max(msg.astype(jnp.uint8), dst, **seg)
-        merged = jnp.maximum(out_init.astype(jnp.uint8), red)
-        return merged.astype(out_init.dtype)
-    raise ValueError(kind)
+    if (kind != "or" and src_val.dtype == out_init.dtype
+            and (jnp.issubdtype(out_init.dtype, jnp.floating)
+                 or not use_weight)):
+        # one gather of pre-masked values: a message from an inactive
+        # source is the neutral element (float weights below 2**100)
+        v = jnp.where(active, src_val, neutral)[nbr]
+        msg = edge_message(v, w, kind, use_weight).astype(out_init.dtype)
+    else:
+        msg = edge_message(src_val[nbr], w, kind, use_weight)
+        msg = jnp.where(active[nbr], msg.astype(out_init.dtype), neutral)
+    n = out_init.shape[0]
+    if out_init.dtype == bool:
+        # over int32 0/1 (a bool max is an or: neutral 0, not int32's min)
+        red = sorted_segment_reduce(dst, msg.astype(jnp.int32),
+                                    "or" if kind == "max" else kind, n,
+                                    row_ptr).astype(bool)
+    else:
+        red = sorted_segment_reduce(dst, msg, kind, n, row_ptr)
+    return _COMBINE[kind](out_init, red)
+
+
+def sorted_segment_reduce(seg, val, kind: str, num_segments: int,
+                          row_ptr=None):
+    """Reduce ``val`` over the runs of equal ``seg`` (sorted ascending) into
+    ``num_segments`` outputs, the neutral element where a segment is empty.
+    A Hillis–Steele segmented scan (log2(len) whole-array passes), then
+    each segment's last slot: gathered through ``row_ptr`` (len
+    ``num_segments + 1``) when given, else scattered with sorted indices
+    and one non-neutral update per segment.  Vector work where an unsorted
+    scatter costs the TPU several nanoseconds per element, and ``add``
+    associates as a fixed tree."""
+    neutral = neutral_for(kind, val.dtype)
+    m = val.shape[0]
+    if m == 0:
+        return jnp.full((num_segments,), neutral, val.dtype)
+    comb = _COMBINE[kind]
+    k = 1
+    while k < m:
+        shifted = jnp.concatenate([jnp.full((k,), neutral, val.dtype),
+                                   val[:-k]])
+        same = jnp.concatenate([jnp.zeros((k,), bool), seg[k:] == seg[:-k]])
+        val = jnp.where(same, comb(val, shifted), val)
+        k *= 2
+    if row_ptr is not None:
+        end = row_ptr[1:]
+        tail = val[jnp.clip(end - 1, 0, m - 1)]
+        return jnp.where(end > row_ptr[:-1], tail, neutral)
+    is_tail = jnp.concatenate([seg[1:] != seg[:-1], jnp.ones((1,), bool)])
+    out = jnp.full((num_segments,), neutral, val.dtype)
+    op = "max" if kind == "or" else kind
+    return getattr(out.at[seg], op)(jnp.where(is_tail, val, neutral),
+                                    indices_are_sorted=True)
 
 
 def sorted_lower_bound(rows, vals):
@@ -199,7 +293,7 @@ def advance_ref(f_idx, f_count, out_deg, row_ptr, col_idx, edge_w,
     cap = f_idx.shape[0]
     in_list = jnp.arange(cap) < jnp.minimum(f_count, cap)
     deg = jnp.where(in_list, out_deg[f_idx], 0)
-    cum = jnp.cumsum(deg)
+    cum = prefix_sum(deg)
     total = cum[-1] if cap > 0 else jnp.int32(0)
     j = jnp.arange(budget, dtype=jnp.int32)
     k = jnp.searchsorted(cum, j, side="right").astype(jnp.int32)
@@ -228,27 +322,11 @@ def relax_ref(src, dst, w, valid, src_val, out_init, kind: str = "min",
 # ---------------------------------------------------------------------------
 # One shared edge-structure fetch amortized over B label lanes: the edge
 # arrays are gathered once, the per-lane values arrive as a (B, n_pad)
-# matrix, and the scatter runs on axis 1 with a shared destination vector.
+# matrix, and the scatter runs on axis 1 with a shared destination vector
+# (``scatter_reduce`` on the (B, n_pad) accumulator).
 # Per lane these compute exactly what push_ref / relax_ref compute, and the
 # min/max/or reductions are order-independent, so each row is bitwise equal
 # to the corresponding single-lane call (pinned by tests/test_multisource).
-
-
-def batched_scatter_reduce(dst, msg, out, kind: str):
-    """Reduce ``msg`` (B, e) into ``out`` (B, n) at axis-1 positions ``dst``."""
-    ref = out.at[:, dst]
-    if kind == "min":
-        return ref.min(msg)
-    if kind == "max":
-        return ref.max(msg)
-    if kind == "add":
-        return ref.add(msg)
-    if kind == "or":
-        if out.dtype == bool:
-            return (out.astype(jnp.uint8)
-                    .at[:, dst].max(msg.astype(jnp.uint8)).astype(bool))
-        return ref.max(msg.astype(out.dtype))
-    raise ValueError(kind)
 
 
 def batched_push_ref(src, dst, w, src_val, active, out_init,
@@ -261,7 +339,7 @@ def batched_push_ref(src, dst, w, src_val, active, out_init,
     msg = edge_message(v, w[None, :], kind, use_weight)
     neutral = neutral_for(kind, out_init.dtype)
     msg = jnp.where(active[:, src], msg.astype(out_init.dtype), neutral)
-    return batched_scatter_reduce(dst, msg, out_init, kind)
+    return scatter_reduce(dst, msg, out_init, kind)
 
 
 def batched_relax_ref(src, dst, w, valid, src_val, active, out_init,
@@ -275,4 +353,4 @@ def batched_relax_ref(src, dst, w, valid, src_val, active, out_init,
     neutral = neutral_for(kind, out_init.dtype)
     msg = jnp.where(valid[None, :] & active[:, src],
                     msg.astype(out_init.dtype), neutral)
-    return batched_scatter_reduce(dst, msg, out_init, kind)
+    return scatter_reduce(dst, msg, out_init, kind)

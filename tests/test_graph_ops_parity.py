@@ -467,3 +467,143 @@ def test_substrate_selection_api():
     with pytest.raises(ValueError):
         sv, active, init = vertex_data(g, "min")
         ops.push_dense(g, sv, active, init, substrate="triton")
+
+
+# ---------------------------------------------------------------------------
+# Scatters split over operand ranges, and compaction (graph_ops.ref)
+# ---------------------------------------------------------------------------
+# On the chip a vertex array of graph500-22 size is scattered one operand
+# range at a time; here ``SCATTER_CHUNK`` is cut so that small arrays take
+# the same split path.
+
+from repro.kernels.graph_ops import ref as gref  # noqa: E402
+
+
+@pytest.mark.parametrize("chunk", [1 << 30, 64, 7])
+@pytest.mark.parametrize("case", ["min", "max", "add", "or_bool", "add_int8",
+                                  "set", "min_lanes"])
+def test_scatter_at_ranges_match_one_scatter(monkeypatch, case, chunk):
+    import jax
+    monkeypatch.setattr(gref, "SCATTER_CHUNK", chunk)
+    rng = np.random.default_rng(3)
+    n, e = 301, 900
+    idx = rng.integers(0, n, e).astype(np.int32)
+    if case == "set":
+        idx = rng.permutation(n)[:200].astype(np.int32)
+        e = 200
+    vals = rng.integers(-50, 50, e).astype(np.float32)
+    if case == "or_bool":
+        out, upd, op = np.zeros(n, bool), vals > 20, "max"
+    elif case == "add_int8":
+        out, upd, op = np.ones(n, np.int8), vals.astype(np.int8), "add"
+    elif case == "min_lanes":
+        out = rng.integers(0, 40, (3, n)).astype(np.float32)
+        upd, op = rng.integers(-50, 50, (3, e)).astype(np.float32), "min"
+    else:
+        out, upd, op = np.full(n, 7.0, np.float32), vals, case
+    f = jax.jit(lambda o, i, u: gref.scatter_at(o, i, u, op))
+    got = np.asarray(f(jnp.asarray(out), jnp.asarray(idx), jnp.asarray(upd)))
+    want = np.asarray(getattr(jnp.asarray(out).at[..., jnp.asarray(idx)],
+                              op)(jnp.asarray(upd)))
+    assert got.dtype == out.dtype
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("capacity", [1, 37, 300, 1111, 3000])
+@pytest.mark.parametrize("density", [0.0, 0.1, 1.0])
+def test_compact_indices_matches_nonzero(monkeypatch, capacity, density):
+    monkeypatch.setattr(gref, "SCATTER_CHUNK", 256)
+    rng = np.random.default_rng(capacity)
+    mask = jnp.asarray(rng.random(2500) < density)
+    got = gref.compact_indices(mask, capacity, 2499)
+    (want,) = jnp.nonzero(mask, size=capacity, fill_value=2499)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("n", [1, 1023, 1024, 5000])
+def test_prefix_sum_matches_cumsum(n):
+    x = jnp.asarray(np.random.default_rng(n).integers(0, 3, n), jnp.int32)
+    np.testing.assert_array_equal(np.asarray(gref.prefix_sum(x)),
+                                  np.cumsum(np.asarray(x)))
+
+
+def test_engine_with_split_scatters_matches_oracle(monkeypatch):
+    """BFS and SSSP through the engine with every vertex-array scatter and
+    compaction split into ranges of a few hundred elements."""
+    import jax
+    from oracles import bfs as bfs_oracle, dijkstra
+    monkeypatch.setattr(gref, "SCATTER_CHUNK", 333)
+    jax.clear_caches()
+    try:
+        src, dst, n = gen.rmat(10, 8, seed=5)
+        w = gen.random_weights(len(src), seed=6)
+        g = from_coo(src, dst, n, w, build_csc=True)
+        dist, _ = bfs.bfs_dd_sparse(from_coo(src, dst, n), 0)
+        hops = np.asarray(dist)[:n]
+        want = np.asarray(bfs_oracle(src, dst, n, 0), np.float64)
+        np.testing.assert_array_equal(
+            np.where(hops >= float(bfs.INF), np.inf, hops), want)
+        sd, _ = sssp.sssp_delta(g, 0, delta=4.0)
+        sd = np.asarray(sd, np.float64)[:n]
+        ref = np.asarray(dijkstra(src, dst, w, n, 0), np.float64)
+        np.testing.assert_allclose(
+            np.where(sd >= float(sssp.INF), np.inf, sd), ref, rtol=1e-5)
+    finally:
+        jax.clear_caches()
+
+
+@pytest.mark.parametrize("gather", [True, False])
+@pytest.mark.parametrize("kind", ["min", "max", "add", "or"])
+def test_sorted_segment_reduce_matches_segment_ops(kind, gather):
+    """The scan-based reduction over destination-sorted edges against
+    ``jax.ops`` segment reductions, with empty segments and runs that
+    cross every power-of-two stride."""
+    import jax
+    rng = np.random.default_rng(11)
+    n = 97
+    deg = rng.integers(0, 9, n)
+    deg[[3, 40, 96]] = 0
+    deg[7] = 300
+    row_ptr = np.concatenate([[0], np.cumsum(deg)]).astype(np.int32)
+    seg = np.repeat(np.arange(n, dtype=np.int32), deg)
+    dtype = bool if kind == "or" else np.float32
+    val = rng.integers(-20, 20, len(seg)).astype(dtype)
+    red = "max" if kind == "or" else kind
+    got = gref.sorted_segment_reduce(jnp.asarray(seg), jnp.asarray(val), red,
+                                     n, jnp.asarray(row_ptr) if gather
+                                     else None)
+    segop = {"min": jax.ops.segment_min, "max": jax.ops.segment_max,
+             "add": jax.ops.segment_sum}[kind if kind != "or" else "max"]
+    want = np.asarray(segop(jnp.asarray(val), jnp.asarray(seg),
+                            num_segments=n, indices_are_sorted=True))
+    empty = deg == 0
+    got = np.asarray(got)
+    neutral = np.asarray(gref.neutral_for(red, val.dtype))
+    np.testing.assert_array_equal(got[~empty], want[~empty])
+    assert np.all(got[empty] == neutral)
+
+
+@pytest.mark.parametrize("gname", list(GRAPHS))
+@pytest.mark.parametrize("kind", KINDS)
+def test_dense_push_over_sorted_edges_matches_scatter(gname, kind):
+    """The jnp dense push reduces over the CSC mirror (and a reversed push
+    over the CSR); on integer data it equals the scatter over the CSR
+    bitwise."""
+    g = build(gname)
+    src_val, active, out_init = vertex_data(g, kind)
+    for reverse in (False, True):
+        s, d = (g.col_idx, g.src_idx) if reverse else (g.src_idx, g.col_idx)
+        want = gref.push_ref(s, d, g.edge_w, src_val, active, out_init, kind)
+        got = ops.push_dense(g, src_val, active, out_init, kind=kind,
+                             substrate="jnp", reverse=reverse)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if kind in ("min", "max"):
+        # a bool min is an AND, a bool max an OR
+        bval, bact = src_val > 0, active
+        binit = jnp.full((g.n_pad,), kind == "min")
+        want = gref.push_ref(g.src_idx, g.col_idx, g.edge_w, bval, bact,
+                             binit, kind, False)
+        got = ops.push_dense(g, bval, bact, binit, kind=kind,
+                             use_weight=False, substrate="jnp")
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

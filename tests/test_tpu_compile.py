@@ -15,6 +15,7 @@ The four ``graph_ops`` Pallas kernels are strict xfails carrying the
 compiler's refusal: the change that makes them compile has to flip them.
 """
 
+import dataclasses
 import os
 
 import jax
@@ -81,6 +82,14 @@ def _vertex(sharding, dtype=jnp.float32):
     return _sds((N_PAD,), dtype, sharding)
 
 
+def _sorts(compiled) -> bool:
+    """Whether the program sorts: the TPU compiler sorts the updates of a
+    scatter into more than a few million elements, or of any 8- or 16-bit
+    scatter, and such a program takes seconds more to compile
+    (``graph_ops.ref.SCATTER_CHUNK``)."""
+    return " sort(" in compiled.as_text()
+
+
 def _device_bytes(compiled) -> int:
     ma = compiled.memory_analysis()
     return (ma.argument_size_in_bytes + ma.output_size_in_bytes
@@ -96,6 +105,45 @@ def test_jnp_push_relax_fits_one_chip(one_chip):
                            _vertex(one_chip, bool),
                            _vertex(one_chip)).compile()
     assert 0 < _device_bytes(compiled) < HBM_BYTES
+    assert not _sorts(compiled)
+
+
+def test_jnp_push_relax_over_csc_fits_one_chip(one_chip):
+    """With a CSC mirror the jnp dense push reduces over destination-sorted
+    in-edges (``graph_ops.sorted_segment_reduce``): no scatter over edges."""
+    i32 = lambda k: _sds((k,), jnp.int32, one_chip)
+    g = dataclasses.replace(
+        _graph(one_chip), in_row_ptr=i32(N_PAD + 1), in_col_idx=i32(M_PAD),
+        in_src_idx=i32(M_PAD), in_edge_w=_sds((M_PAD,), jnp.float32,
+                                              one_chip), in_deg=i32(N_PAD))
+    relax = jax.jit(lambda g, v, a, o: ops.push_dense(
+        g, v, a, o, kind="add", use_weight=False, substrate="jnp"))
+    compiled = relax.lower(g, _vertex(one_chip), _vertex(one_chip, bool),
+                           _vertex(one_chip)).compile()
+    assert 0 < _device_bytes(compiled) < HBM_BYTES
+    assert not _sorts(compiled) and " scatter(" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("kind", ["add", "or"])
+def test_jnp_push_relax_scatters_without_sort(one_chip, kind):
+    """The push relax of PageRank (add) and of reachability (or, on a bool
+    vertex array) over every edge of graph500-22."""
+    dt = bool if kind == "or" else jnp.float32
+    relax = jax.jit(lambda g, v, a, o: ops.push_dense(
+        g, v, a, o, kind=kind, use_weight=False, substrate="jnp"))
+    compiled = relax.lower(_graph(one_chip), _vertex(one_chip, dt),
+                           _vertex(one_chip, bool),
+                           _vertex(one_chip, dt)).compile()
+    assert not _sorts(compiled)
+
+
+def test_frontier_compaction_without_sort(one_chip):
+    """Compacting a vertex mask into every rung of the capacity ladder."""
+    caps = fr.ladder_capacities(N_PAD, BLOCK)
+    for cap in (caps[0], caps[-2], caps[-1]):
+        compiled = jax.jit(lambda m: fr.compact(m, cap, N_PAD - 1)).lower(
+            _vertex(one_chip, bool)).compile()
+        assert not _sorts(compiled), cap
 
 
 def test_sparse_ladder_bfs_stretch_fits_one_chip(one_chip):
@@ -111,7 +159,9 @@ def test_sparse_ladder_bfs_stretch_fits_one_chip(one_chip):
         budget=budget, lo_cap=fr.ladder_below(cap, caps),
         lo_budget=fr.ladder_below(budget, budgets),
         cutoff=budgets[-1] // 2, sub="jnp", det=False)
-    assert 0 < _device_bytes(lowered.compile()) < HBM_BYTES
+    compiled = lowered.compile()
+    assert 0 < _device_bytes(compiled) < HBM_BYTES
+    assert not _sorts(compiled)
 
 
 def test_tiered_shard_relax_fits_one_chip(one_chip):
@@ -123,6 +173,7 @@ def test_tiered_shard_relax_fits_one_chip(one_chip):
         _vertex(one_chip, bool), _vertex(one_chip), kind="min",
         use_weight=True, sub="jnp", det=False, reverse=False).compile()
     assert 0 < _device_bytes(compiled) < HBM_BYTES
+    assert not _sorts(compiled)
 
 
 def test_sharded_relax_fits_four_chips(topo):
