@@ -19,7 +19,6 @@ instead of throwing the numbers away with the process.
   serving      §Serving     multi-source batched queries: amortization + QPS
   dynamic      §Dynamic     edge-log deltas: incremental vs full recompute
   kernels      —            Pallas kernel µs/call
-  roofline     §Roofline    reads experiments/dryrun/*.json
 """
 
 import argparse
@@ -31,7 +30,7 @@ from repro.compile_cache import enable_compile_cache
 
 from . import (algo_classes, common, comm_volume, dynamic, frameworks,
                granularity, kernels_bench, memtier, outofcore, placement,
-               roofline, scaling, serving, vs_cluster)
+               scaling, serving, vs_cluster)
 
 SUITES = {
     "memtier": memtier,
@@ -46,7 +45,6 @@ SUITES = {
     "serving": serving,
     "dynamic": dynamic,
     "kernels": kernels_bench,
-    "roofline": roofline,
 }
 
 

@@ -68,6 +68,7 @@ import numpy as np
 
 from . import frontier as fr
 from . import operators as ops
+from . import spans
 from .graph import Graph
 
 
@@ -106,6 +107,21 @@ class RunStats:
     io_retries: int = 0
     checksum_failures: int = 0
     io_wait_us: int = 0
+    # io_wait_us by phase (host read with retries, CRC32, device_put
+    # issue), the misses begun with the device drained of their relax,
+    # and the longest single miss of the run (a maximum, not a sum)
+    read_us: int = 0
+    crc_us: int = 0
+    put_us: int = 0
+    fetch_exposed_us: int = 0
+    fetch_max_us: int = 0
+    # SparseLadderEngine: edge slots the sparse rounds charged (part of
+    # edges_touched; exact), and host time from each stretch's dispatch to
+    # the blocking fetch that settles it, summed by regime (the
+    # ``engine.stretch`` spans' durations)
+    sparse_edges_touched: int = 0
+    sparse_us: int = 0
+    dense_us: int = 0
     # direction-optimizing traversal: rounds executed in the pull (CSC)
     # direction — those are charged by in-degree scan mass, not m
     pull_rounds: int = 0
@@ -217,12 +233,13 @@ def run_host(
     executes at most ``max_rounds - r`` more."""
     state, rounds = resume_run(checkpointer, state)
     while rounds < max_rounds and bool(cond(state)):
-        if fault is not None:
-            fault.tick("round", key=rounds)
-        state = step(state)
-        rounds += 1
-        if checkpointer is not None:
-            checkpointer.maybe_save(state, rounds)
+        with spans.Span("engine.round", round=rounds):
+            if fault is not None:
+                fault.tick("round", key=rounds)
+            state = step(state)
+            rounds += 1
+            if checkpointer is not None:
+                checkpointer.maybe_save(state, rounds)
     return rounds, state
 
 
@@ -629,8 +646,20 @@ class SparseLadderEngine:
             self.stats.sparse_rounds += k
             self.stats.shard_escalations += esc
             # per round: budget·(ndev − esc_r) + epd·esc_r, summed over k
-            self.stats.edges_touched += budget * (k * ndev - esc) + epd * esc
+            slots = budget * (k * ndev - esc) + epd * esc
+            self.stats.edges_touched += slots
+            self.stats.sparse_edges_touched += slots
             self.stats.add_comm(g, relaxes=k, scalar_collectives=k)
+
+    def _close_stretch(self, regime, span):
+        """End a stretch's ``engine.stretch`` span at the blocking fetch
+        that settled it, and charge its host time to the regime's
+        timer."""
+        us = span.close() // 1000
+        if regime == "dense":
+            self.stats.dense_us += us
+        else:
+            self.stats.sparse_us += us
 
     def _run_fused(self, labels, mask, max_rounds: int, checkpointer=None):
         g = self.g
@@ -640,7 +669,7 @@ class SparseLadderEngine:
         sparse_cutoff = self.budget_ladder[-1] // 2
         (labels, mask), round_no = resume_run(checkpointer, (labels, mask))
         scalars = _round_scalars(g, mask)
-        pending = None  # (regime, budget) of the stretch in flight
+        pending = None  # (regime, budget, span) of the stretch in flight
         counters = None
         rounds_left = max_rounds - round_no
         while True:
@@ -653,6 +682,7 @@ class SparseLadderEngine:
                     int(x) for x in jax.device_get(scalars))
             else:
                 sc, cnt = jax.device_get((scalars, counters))
+                self._close_stretch(pending[0], pending[2])
                 count, cap_need, mass_med, _ = (int(x) for x in sc)
                 k, esc, dmass = (int(x) for x in cnt)
                 self._settle_stretch(pending[0], pending[1], k, esc, dmass)
@@ -687,20 +717,24 @@ class SparseLadderEngine:
                 mass_cap = max(1, (2**31 - 1) // max(g.m, 1))
                 limit = jnp.int32(min(rounds_left, mass_cap))
                 self._note_stretch(("dense", sub, det))
+                span = spans.Span("engine.stretch", regime="dense",
+                                  capacity=0, budget=0)
                 labels, mask, scalars, k_dev, mass_dev = _dense_stretch(
                     g, labels, mask, scalars, limit, step=self._dense_fn,
                     cutoff=sparse_cutoff, sub=sub, det=det)
-                pending = ("dense", 0)
+                pending = ("dense", 0, span)
                 counters = (k_dev, jnp.int32(0), mass_dev)
             else:
                 self._note_stretch(("sparse", cap, budget, sub, det))
+                span = spans.Span("engine.stretch", regime="sparse",
+                                  capacity=cap, budget=budget)
                 labels, mask, scalars, k_dev, esc_dev = _sparse_stretch(
                     g, labels, mask, scalars, limit, step=self._sparse_fn,
                     capacity=cap, budget=budget,
                     lo_cap=fr.ladder_below(cap, self.cap_ladder),
                     lo_budget=fr.ladder_below(budget, self.budget_ladder),
                     cutoff=sparse_cutoff, sub=sub, det=det)
-                pending = ("sparse", budget)
+                pending = ("sparse", budget, span)
                 counters = (k_dev, esc_dev, jnp.int32(0))
         return labels, mask
 
@@ -724,9 +758,13 @@ class SparseLadderEngine:
         # max sparse budget: don't bother with sparse when it costs ~ dense
         sparse_cutoff = self.budget_ladder[-1] // 2
         (labels, mask), rnd = resume_run(checkpointer, (labels, mask))
+        dense_span = None  # a dense round settles at the next fetch
         while rnd < max_rounds:
             count, cap_need, mass_med, mass_tot = (
                 int(x) for x in jax.device_get(_round_scalars(g, mask)))
+            if dense_span is not None:
+                self._close_stretch("dense", dense_span)
+                dense_span = None
             if count == 0:
                 break
             self.stats.rounds += 1
@@ -746,22 +784,32 @@ class SparseLadderEngine:
             # hub-heavy minority outgrows the rung, the round stays sparse
             # and those shards escalate locally inside the step
             if mass_med > sparse_cutoff or overflow:
+                dense_span = spans.Span("engine.stretch", regime="dense",
+                                        capacity=0, budget=0)
                 labels, mask = self._get_dense()(g, labels, mask)
                 self.stats.dense_rounds += 1
                 self.stats.edges_touched += (
                     mass_tot if self.dense_cost == "mass" else g.m)
                 self.stats.add_comm(g, relaxes=1)
             else:
+                span = spans.Span("engine.stretch", regime="sparse",
+                                  capacity=cap, budget=budget)
                 labels, mask, esc = self._get_sparse(cap, budget)(
                     g, labels, mask, capacity=cap, budget=budget
                 )
-                esc = int(esc)
+                esc = int(esc)  # the blocking fetch that settles the round
+                self._close_stretch("sparse", span)
+                slots = budget * (ndev - esc) + epd * esc
                 self.stats.shard_escalations += esc
                 self.stats.sparse_rounds += 1
-                self.stats.edges_touched += budget * (ndev - esc) + epd * esc
+                self.stats.edges_touched += slots
+                self.stats.sparse_edges_touched += slots
                 self.stats.add_comm(g, relaxes=1, scalar_collectives=1)
             rnd += 1
             if checkpointer is not None:
                 checkpointer.maybe_save((labels, mask), rnd,
                                         self.stats.as_dict())
+        if dense_span is not None:
+            # the round budget ran out with no fetch left to settle it
+            self._close_stretch("dense", dense_span)
         return labels, mask

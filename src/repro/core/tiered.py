@@ -21,12 +21,15 @@ device-resident.  Edge shards are streamed into a small pool of
   schedule).  Work-efficient ⇒ bandwidth-efficient: the H2D traffic of a
   run is proportional to the edges its frontiers actually touched, not to
   rounds × |CSR|.
-* **Double-buffered streaming** — while shard *i* relaxes, shard *i+1*'s
-  H2D copy is already in flight (``jax.device_put`` is async; the relax
-  dispatch is async too, so the copy overlaps the previous shard's
-  compute).  The pool is LRU: shards still resident from an earlier round
-  are **buffer hits** and cost zero bytes — frontier locality across
-  rounds is free, exactly the paper's DRAM-cache argument.
+* **Double-buffered streaming** — while shard *i* relaxes, the host
+  fetches shard *i+1* (read, CRC32, ``jax.device_put``; the relax
+  dispatch is async, so that fetch overlaps shard *i*'s compute).  The
+  first two fetches of each relax are the exception: both run before its
+  first dispatch, while the device has nothing of this relax to run, so
+  they stall it (``StreamIO.fetch_exposed_us`` counts such fetches).  The
+  pool is LRU: shards still resident from an earlier round are **buffer
+  hits** and cost zero bytes — frontier locality across rounds is free,
+  exactly the paper's DRAM-cache argument.
 * **One executable for every shard** — shards are padded to one uniform
   ``epd`` slot count, so the per-shard relax jits **once** per
   (kind, substrate, mode) and replays for every shard of every round (the
@@ -86,6 +89,7 @@ import numpy as np
 
 from ..distributed.fault import RetryPolicy
 from ..kernels import graph_ops as gk
+from . import spans
 from .faultio import FaultInjector, ShardCorruptError
 from .graph import Graph, round_up, shard_ranges
 
@@ -114,31 +118,57 @@ class StreamIO:
     # checksum mismatches observed (every one either healed on retry or
     # became a ShardCorruptError), and wall time the fetch path spent on
     # misses — host read + verify + H2D issue + retry backoff, the
-    # latency a fault plan's delay spikes land in
+    # latency a fault plan's delay spikes land in (the ``tier.fetch``
+    # spans' durations)
     io_retries: int = 0
     checksum_failures: int = 0
     io_wait_us: int = 0
+    # io_wait_us split by phase (``tier.read`` with its retries and
+    # backoff, ``tier.crc``, ``tier.put``: the three device_put issues);
+    # the parts sum to at most io_wait_us (eviction is in neither)
+    read_us: int = 0
+    crc_us: int = 0
+    put_us: int = 0
+    # misses that began while the device held no unfinished work of their
+    # relax (its newest device value already ready): a lower bound on the
+    # idle time fetches cause.  A fetch behind a pull still in flight
+    # counts nothing here, however long it outlasts that pull.
+    fetch_exposed_us: int = 0
+    # the longest single miss since the innermost open ``snapshot()``: a
+    # maximum, not a running sum, so snapshot/fold_delta treat it apart
+    fetch_max_us: int = 0
+
+    _SUMS = ("h2d_bytes", "shards_streamed", "buffer_hits", "edges_relaxed",
+             "io_retries", "checksum_failures", "io_wait_us", "read_us",
+             "crc_us", "put_us", "fetch_exposed_us")
 
     def snapshot(self) -> Tuple[int, ...]:
-        return (self.h2d_bytes, self.shards_streamed, self.buffer_hits,
-                self.edges_relaxed, self.io_retries, self.checksum_failures,
-                self.io_wait_us)
+        """The counters as they stand, to fold a run's delta from later.
+        Also starts a new ``fetch_max_us`` window (the one it interrupts
+        is restored by the matching ``fold_delta``, so snapshots may
+        nest)."""
+        before = tuple(getattr(self, f) for f in self._SUMS) + (
+            self.fetch_max_us,)
+        self.fetch_max_us = 0
+        return before
 
     def fold_delta(self, stats, before: Tuple[int, ...],
                    include_edges: bool = True) -> None:
-        """Add the counters accumulated since ``before`` into a RunStats.
+        """Add the counters accumulated since ``before`` into a RunStats;
+        ``stats.fetch_max_us`` becomes at least the longest miss since
+        then.
 
         ``include_edges=False`` folds only the streaming/IO counters —
         for algorithms (bfs_dirop) that charge ``edges_touched`` by their
         own work convention rather than by relaxed edge slots."""
-        stats.h2d_bytes += self.h2d_bytes - before[0]
-        stats.shards_streamed += self.shards_streamed - before[1]
-        stats.buffer_hits += self.buffer_hits - before[2]
-        if include_edges:
-            stats.edges_touched += self.edges_relaxed - before[3]
-        stats.io_retries += self.io_retries - before[4]
-        stats.checksum_failures += self.checksum_failures - before[5]
-        stats.io_wait_us += self.io_wait_us - before[6]
+        for f, b in zip(self._SUMS, before):
+            if f == "edges_relaxed":
+                if include_edges:
+                    stats.edges_touched += self.edges_relaxed - b
+                continue
+            setattr(stats, f, getattr(stats, f) + getattr(self, f) - b)
+        stats.fetch_max_us = max(stats.fetch_max_us, self.fetch_max_us)
+        self.fetch_max_us = max(self.fetch_max_us, before[-1])
 
 
 @partial(jax.jit, static_argnames=("kind", "use_weight", "sub", "det",
@@ -433,13 +463,16 @@ class TieredGraph:
         shards tick the same ``shard_read`` fault site under the key
         ``nshards + sid`` so plans can target either direction."""
         csc = direction == "csc"
-        s, d, w = (self._csc_host if csc else self._host)[sid]
-        if self.fault is not None:
-            s, d, w = self.fault.shard_read(self.nshards + sid if csc
-                                            else sid, s, d, w)
+        with spans.Span("tier.read"):
+            s, d, w = (self._csc_host if csc else self._host)[sid]
+            if self.fault is not None:
+                s, d, w = self.fault.shard_read(self.nshards + sid if csc
+                                                else sid, s, d, w)
         crcs = self.in_shard_crcs if csc else self.shard_crcs
         if self.verify_checksums and crcs is not None:
-            got = shard_crc(s, d, w)
+            with spans.Span("tier.crc") as crc:
+                got = shard_crc(s, d, w)
+            self.io.crc_us += crc.us
             want = crcs[sid]
             if got != want:
                 self.io.checksum_failures += 1
@@ -465,31 +498,57 @@ class TieredGraph:
         device_put — a corrupt shard raises :class:`ShardCorruptError`
         out of the relax instead of folding garbage into labels.  The
         counters stay exact under retries: one successful miss charges
-        exactly one ``shard_bytes``, however many attempts it took."""
+        exactly one ``shard_bytes``, however many attempts it took.
+
+        A miss is one ``tier.fetch`` span (children ``tier.read``,
+        ``tier.crc``, ``tier.put``)."""
         pool = self._pool
         key = (direction, sid)
         if key in pool:
             pool.move_to_end(key)
             self.io.buffer_hits += 1
             return pool[key]
-        t0 = time.perf_counter()
-        while len(pool) >= self.resident_shards:
-            pool.popitem(last=False)
-
-        def count_retry(attempt, delay_s, exc):
-            self.io.io_retries += 1
-
+        fetch = spans.Span("tier.fetch", sid=sid, direction=direction)
         try:
-            s, d, w = self.retry.run(self._read_shard, sid, direction,
-                                     on_retry=count_retry)
-            # one async H2D per array: jax.device_put returns immediately,
-            # so the copy overlaps the previous shard's relax dispatch
-            buf = (jax.device_put(s), jax.device_put(d), jax.device_put(w))
+            while len(pool) >= self.resident_shards:
+                pool.popitem(last=False)
+
+            def count_retry(attempt, delay_s, exc):
+                self.io.io_retries += 1
+
+            crc0, t0 = self.io.crc_us, time.perf_counter_ns()
+            try:
+                s, d, w = self.retry.run(self._read_shard, sid, direction,
+                                         on_retry=count_retry)
+            finally:
+                self.io.read_us += ((time.perf_counter_ns() - t0) // 1000
+                                    - (self.io.crc_us - crc0))
+            # one async H2D per array: jax.device_put returns once the
+            # host has handed each array over, and the copy itself
+            # overlaps whatever the device is running
+            with spans.Span("tier.put") as put:
+                buf = (jax.device_put(s), jax.device_put(d),
+                       jax.device_put(w))
+            self.io.put_us += put.us
         finally:
-            self.io.io_wait_us += int((time.perf_counter() - t0) * 1e6)
+            us = fetch.close() // 1000
+            self.io.io_wait_us += us
+            self.io.fetch_max_us = max(self.io.fetch_max_us, us)
         pool[key] = buf
         self.io.shards_streamed += 1
         self.io.h2d_bytes += self.shard_bytes
+        return buf
+
+    def _fetch_behind(self, newest, sid: int, direction: str = "csr"):
+        """``_fetch`` for a relax whose newest device value is ``newest``:
+        if that value is already ready, the device holds no unfinished
+        work of this relax, so a miss starting now stalls it and counts in
+        ``fetch_exposed_us``."""
+        drained = newest.is_ready()
+        wait0 = self.io.io_wait_us
+        buf = self._fetch(sid, direction)
+        if drained:
+            self.io.fetch_exposed_us += self.io.io_wait_us - wait0
         return buf
 
     def _schedule(self, active) -> list[int]:
@@ -510,7 +569,9 @@ class TieredGraph:
         work-efficiency at shard granularity).
 
         Scheduled shards fold into the accumulator in ascending shard
-        order while the next shard's copy is in flight (double buffering).
+        order; the host fetches the next shard while the device relaxes
+        the current one (double buffering), except for the first two
+        fetches, which precede the first dispatch.
         ``reverse=True`` (bc's backward sweep) activates on destinations,
         which any shard may hold — it schedules every shard.
         """
@@ -526,11 +587,15 @@ class TieredGraph:
         acc = out_init
         if not sched:
             return acc
-        cur = self._fetch(sched[0])
+        # the first two fetches run before the first dispatch, with the
+        # device drained of this relax; every later one is issued behind
+        # the relax of the shard before it
+        cur = self._fetch_behind(src_val, sched[0])
         for i, sid in enumerate(sched):
             buf = cur
             if i + 1 < len(sched):
-                cur = self._fetch(sched[i + 1])  # prefetch overlaps relax
+                cur = self._fetch_behind(src_val if i == 0 else acc,
+                                         sched[i + 1])
             acc = _shard_relax(buf[0], buf[1], buf[2], src_val, active, acc,
                                kind=kind, use_weight=use_weight,
                                sub=substrate, det=det, reverse=reverse)
@@ -554,11 +619,14 @@ class TieredGraph:
                 "graph built with from_coo(..., build_csc=True))")
         self.io.edges_relaxed += int(self.in_shard_sizes.sum())
         acc = out_init
-        cur = self._fetch(0, "csc")
+        # as in tiered_push_dense: fetches 0 and 1 stall the device, the
+        # rest overlap the previous shard's pull
+        cur = self._fetch_behind(src_val, 0, "csc")
         for sid in range(self.nshards):
             buf = cur
             if sid + 1 < self.nshards:
-                cur = self._fetch(sid + 1, "csc")  # prefetch overlaps relax
+                cur = self._fetch_behind(src_val if sid == 0 else acc,
+                                         sid + 1, "csc")
             acc = _shard_pull(buf[0], buf[1], buf[2], src_val, active, acc,
                               kind=kind, use_weight=use_weight,
                               sub=substrate, det=det)

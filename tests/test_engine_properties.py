@@ -219,7 +219,7 @@ def test_bc_edges_touched_counts_fwd_and_bwd_sweeps(gn, src_seed):
 _STAT_FIELDS = ("rounds", "edges_touched", "dense_rounds", "sparse_rounds",
                 "overflow_escalations", "shard_escalations", "comm_elems",
                 "comm_bytes", "reduce_axis_hops", "ndev", "placement",
-                "substrate")
+                "substrate", "sparse_edges_touched")
 
 
 def assert_stats_equal(st_fused, st_per_round, ctx=""):
