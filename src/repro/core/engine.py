@@ -31,12 +31,15 @@ Two execution regimes, mirroring the paper's §5 classification:
   to it (``tests/test_engine_properties.py``).
 
   Rung selection is unchanged by fusion.  When the frontier's median edge
-  mass exceeds the largest sparse budget, the engine falls back to the
-  dense step (direction-optimizing style).  On a sharded graph the ladder
-  is **per shard**: the capacity rung is sized by the largest *local*
-  frontier (active vertices with local edges), the budget rung by the
-  *median* per-shard edge mass, and a hub-heavy shard whose mass outgrows
-  the rung escalates alone to its shard-local dense relax inside the step
+  mass exceeds ``frontier.dense_cutoff`` — at most the rung below the
+  top budget rung, since a sparse round on the top rung (the whole edge
+  array) touches as many slots as the dense sweep and pays compaction
+  besides — the engine runs the dense step (direction-optimizing style).
+  On a sharded graph the ladder is **per shard**: the capacity rung is
+  sized by the largest *local* frontier (active vertices with local
+  edges), the budget rung by the *median* per-shard edge mass, and a
+  hub-heavy shard whose mass outgrows the rung escalates alone to its
+  shard-local dense relax inside the step
   (``RunStats.shard_escalations``) instead of forcing a global dense
   round; the escalation ``psum`` stays in the while_loop carry as a
   device int32, never fetched per round.  Fused stretches are jitted at
@@ -666,7 +669,7 @@ class SparseLadderEngine:
         sub = ops.get_substrate()
         det = ops.get_deterministic_add()
         self.stats.substrate = sub
-        sparse_cutoff = self.budget_ladder[-1] // 2
+        sparse_cutoff = fr.dense_cutoff(self.budget_ladder)
         (labels, mask), round_no = resume_run(checkpointer, (labels, mask))
         scalars = _round_scalars(g, mask)
         pending = None  # (regime, budget, span) of the stretch in flight
@@ -755,8 +758,9 @@ class SparseLadderEngine:
         self.stats.substrate = ops.get_substrate()
         ndev = self.stats.ndev
         epd = getattr(g, "epd", g.m_pad)
-        # max sparse budget: don't bother with sparse when it costs ~ dense
-        sparse_cutoff = self.budget_ladder[-1] // 2
+        # largest mass that runs sparse: the top budget rung never does,
+        # since it costs at least the dense sweep
+        sparse_cutoff = fr.dense_cutoff(self.budget_ladder)
         (labels, mask), rnd = resume_run(checkpointer, (labels, mask))
         dense_span = None  # a dense round settles at the next fetch
         while rnd < max_rounds:

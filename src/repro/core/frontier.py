@@ -21,6 +21,13 @@ exist.  We adapt the idea with two constructions:
   (few big "pages" instead of many small ones).  Overflow is detected
   (``count > capacity``) and the engine falls back to the dense kernel for
   that round, mirroring direction-optimizing switches.
+
+The budget ladder (edge slots a sparse round may expand) tops out at the
+whole edge array.  A sparse round on that top rung touches at least as many
+slots as the dense sweep and pays compaction and the merge-path search
+besides, so it never runs: ``dense_cutoff`` puts the switch to the dense
+step below the top rung, and ``sparse_band`` / ``dense_band`` re-derive
+that switch on device.
 """
 
 from __future__ import annotations
@@ -129,6 +136,25 @@ def pick_capacity(count: int, ladder: Tuple[int, ...]) -> int:
     return ladder[-1]
 
 
+def dense_cutoff(budget_ladder: Tuple[int, ...]) -> int:
+    """The largest median frontier edge mass that still runs sparse; a
+    round above it runs the dense step.
+
+    The top budget rung is the whole edge array (``m_pad``, or ``epd`` per
+    shard on a sharded graph).  A sparse round there touches at least as
+    many edge slots as the dense sweep, plus compaction and the merge-path
+    search, so it can never be cheaper: the cutoff is the rung below the
+    top, and every request above it is one ``pick_capacity`` would have
+    put on the top rung.  It is never above half the edge array either,
+    so no round that ran dense under that older rule runs sparse now (on
+    a ladder whose second rung lies above the half).  A one-rung ladder
+    has no rung below the top: its cutoff stays half of it."""
+    half = budget_ladder[-1] // 2
+    if len(budget_ladder) < 2:
+        return half
+    return min(budget_ladder[-2], half)
+
+
 def ladder_below(rung: int, ladder: Tuple[int, ...]) -> int:
     """The next-smaller rung (0 below the smallest): the lower edge of
     ``rung``'s band.  ``pick_capacity`` returns ``rung`` exactly for
@@ -184,7 +210,9 @@ def sparse_band(scalars, capacity: int, lo_cap: int, budget: int,
     (capacity, budget) sparse rung for ``scalars``: the frontier is alive,
     neither ladder dimension outgrew its rung (``pick_capacity`` would
     move up), neither shrank past the rung's lower edge (a smaller rung
-    pays), and the median mass stays under the dense cutoff."""
+    pays), and the median mass stays at or under the dense cutoff
+    (``dense_cutoff``: below the top budget rung, which never runs
+    sparse)."""
     count, cap_need, mass_med, _ = scalars
     cn = jnp.maximum(cap_need, 1)
     bm = jnp.maximum(mass_med, 1)
@@ -247,7 +275,8 @@ def live_stable(sg, mask: jax.Array) -> jax.Array:
 
 def dense_band(scalars, sparse_cutoff: int) -> jax.Array:
     """True while the host dispatcher would keep picking the dense
-    fallback: frontier alive and median mass above the sparse cutoff.
+    step: frontier alive and median mass above the cutoff
+    (``dense_cutoff``: every mass that would need the top budget rung).
     (The overflow backstop also dispatches dense, but a rung picked by
     ``pick_capacity`` always covers its request, so overflow can never
     arise *mid-stretch* — an overflow-entered stretch simply runs its one

@@ -165,7 +165,7 @@ class MultiSourceEngine:
                                                ladder_base)
         self.budget_ladder = fr.ladder_capacities(g.m_pad, g.block_size,
                                                   ladder_base)
-        self.sparse_cutoff = self.budget_ladder[-1] // 2
+        self.sparse_cutoff = fr.dense_cutoff(self.budget_ladder)
         self._sparse_fn = sparse_step
         self._dense_fn = dense_step
         self._sparse = {}

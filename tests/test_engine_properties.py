@@ -263,11 +263,13 @@ def test_fused_engine_equals_per_round_kcore_mass_accounting():
         assert_stats_equal(st_f, st_p, f"kcore k={k}")
     assert st_f.dense_rounds + st_f.sparse_rounds == st_f.rounds
     # a cell whose peel crosses the dense cutoff, so the fused dense
-    # stretch's on-device mass accumulator is genuinely compared
+    # stretch's on-device mass accumulator is genuinely compared (k = 7:
+    # two dense rounds, then one sparse; since the top budget rung runs
+    # dense, k = 8 peels dense throughout)
     src, dst, n = gen.web_crawl_like(10, 4, 9, 3, seed=0)
     g = from_coo(src, dst, n, block_size=16, symmetrize=True)
-    alive_f, st_f = kcore.kcore_dd_sparse(g, 8, fused=True)
-    alive_p, st_p = kcore.kcore_dd_sparse(g, 8, fused=False)
+    alive_f, st_f = kcore.kcore_dd_sparse(g, 7, fused=True)
+    alive_p, st_p = kcore.kcore_dd_sparse(g, 7, fused=False)
     assert st_f.dense_rounds > 0 and st_f.sparse_rounds > 0
     assert np.array_equal(np.asarray(alive_f), np.asarray(alive_p))
     assert_stats_equal(st_f, st_p, "kcore dense-mass cell")

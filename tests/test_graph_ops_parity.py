@@ -229,7 +229,10 @@ def test_ladder_engine_escalates_instead_of_dropping(monkeypatch):
     from repro.core import engine as engine_mod
     from repro.core.algorithms.bfs import bfs_dd_sparse
 
-    g = build("hub_leaves", csc=False)  # hub round: edge mass 70 > rung 64
+    # hub round: edge mass 70 > the lowest budget rung 32, and at or under
+    # the dense cutoff 80 (budget ladder 32, 128, 160), so it runs sparse;
+    # at block 64 (ladder 64, 192) it would need the top rung and run dense
+    g = build("hub_leaves", block=32, csc=False)
     ref, ref_stats = bfs_dd_sparse(g, 0)
     assert ref_stats.overflow_escalations == 0  # normal runs never overflow
 

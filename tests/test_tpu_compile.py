@@ -158,7 +158,7 @@ def test_sparse_ladder_bfs_stretch_fits_one_chip(one_chip):
         (scalar,) * 4, scalar, step=bfs._sparse_step, capacity=cap,
         budget=budget, lo_cap=fr.ladder_below(cap, caps),
         lo_budget=fr.ladder_below(budget, budgets),
-        cutoff=budgets[-1] // 2, sub="jnp", det=False)
+        cutoff=fr.dense_cutoff(budgets), sub="jnp", det=False)
     compiled = lowered.compile()
     assert 0 < _device_bytes(compiled) < HBM_BYTES
     assert not _sorts(compiled)
