@@ -10,7 +10,7 @@
 
 from __future__ import annotations
 
-from functools import lru_cache
+from functools import lru_cache, partial
 from typing import NamedTuple
 
 import jax
@@ -42,8 +42,32 @@ def pr_pull(
     with a CSC mirror the rounds dispatch eagerly (``run_host``) — every
     iteration is a dense pull, streaming the whole in-edge cut through
     the buffer pool; float sums associate per shard, so results are
-    allclose (not bitwise) to the resident run."""
+    allclose (not bitwise) to the resident run.  Otherwise the whole run
+    is one jitted program, compiled once per graph shape, ``damping``,
+    ``tol`` and operator mode: runs of any ``max_iters`` share it."""
     assert g.has_csc
+    tiered = getattr(g, "is_tiered", False)
+    if tiered:
+        io0 = g.io.snapshot()
+        step, state0 = _pull_program(g, damping)
+        rounds, (rank, _) = run_host(step, state0, lambda s: s[1] > tol,
+                                     max_iters)
+    else:
+        rounds, rank = _pr_pull_resident(
+            g, jnp.int32(max_iters), damping=damping, tol=tol,
+            sub=ops.get_substrate(), det=ops.get_deterministic_add())
+    rounds = int(rounds)
+    stats = RunStats.from_graph(g, relaxes=rounds, rounds=rounds,
+                                edges_touched=0 if tiered else rounds * g.m,
+                                dense_rounds=rounds)
+    if tiered:
+        g.io.fold_delta(stats, io0)
+    return rank, stats
+
+
+def _pull_program(g, damping: float):
+    """``(step, state0)`` of ``pr_pull``'s power iteration on ``g``: state
+    is ``(rank, resid)``, ``resid`` the L1 change of the last step."""
     n = jnp.float32(g.n)
     valid = g.valid_vertex_mask()
     outdeg = jnp.maximum(g.out_deg.astype(jnp.float32), 1.0)
@@ -61,18 +85,16 @@ def pr_pull(
         resid = jnp.sum(jnp.abs(new - rank))
         return new, resid
 
-    tiered = getattr(g, "is_tiered", False)
-    io0 = g.io.snapshot() if tiered else None
-    runner = run_host if tiered else run_dense
-    rounds, (rank, resid) = runner(
-        step, (rank0, jnp.float32(jnp.inf)), lambda s: s[1] > tol, max_iters
-    )
-    stats = RunStats.from_graph(g, relaxes=int(rounds), rounds=int(rounds),
-                                edges_touched=0 if tiered else int(rounds) * g.m,
-                                dense_rounds=int(rounds))
-    if tiered:
-        g.io.fold_delta(stats, io0)
-    return rank, stats
+    return step, (rank0, jnp.float32(jnp.inf))
+
+
+@partial(jax.jit, static_argnames=("damping", "tol", "sub", "det"))
+def _pr_pull_resident(g, max_iters, *, damping, tol, sub, det):
+    with ops.substrate_scope(sub), ops.deterministic_add_scope(det):
+        step, state0 = _pull_program(g, damping)
+        rounds, (rank, _) = run_dense(step, state0, lambda s: s[1] > tol,
+                                      max_iters)
+    return rounds, rank
 
 
 @lru_cache(maxsize=None)

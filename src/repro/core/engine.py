@@ -125,6 +125,10 @@ class RunStats:
     sparse_edges_touched: int = 0
     sparse_us: int = 0
     dense_us: int = 0
+    # blocking fetches that settled a stretch, one per closed
+    # ``engine.stretch`` span: one per stretch fused, one per round
+    # otherwise (a host-path count, so the two paths differ in it)
+    stretches: int = 0
     # direction-optimizing traversal: rounds executed in the pull (CSC)
     # direction — those are charged by in-degree scan mass, not m
     pull_rounds: int = 0
@@ -656,9 +660,10 @@ class SparseLadderEngine:
 
     def _close_stretch(self, regime, span):
         """End a stretch's ``engine.stretch`` span at the blocking fetch
-        that settled it, and charge its host time to the regime's
-        timer."""
+        that settled it, count that fetch, and charge its host time to the
+        regime's timer."""
         us = span.close() // 1000
+        self.stats.stretches += 1
         if regime == "dense":
             self.stats.dense_us += us
         else:

@@ -90,6 +90,21 @@ def test_pagerank(gname, variant):
     np.testing.assert_allclose(rank, ref, rtol=2e-3, atol=1e-8)
 
 
+def test_resident_pr_pull_compiles_once_for_any_iteration_count():
+    """A resident run is one jitted program, shared by runs of any
+    ``max_iters``: a benchmark's one-iteration warm-up compiles what its
+    ten-iteration jobs run."""
+    g, s, d, _, n = build("grid", symmetrize=True, csc=True)
+    pagerank.pr_pull(g, tol=0.0, max_iters=1)
+    programs = pagerank._pr_pull_resident._cache_size()
+    rank3, st3 = pagerank.pr_pull(g, tol=0.0, max_iters=3)
+    rank10, st10 = pagerank.pr_pull(g, tol=0.0, max_iters=10)
+    assert pagerank._pr_pull_resident._cache_size() == programs
+    assert (st3.rounds, st10.rounds) == (3, 10)
+    assert st10.edges_touched == 10 * g.m
+    assert not np.array_equal(np.asarray(rank3), np.asarray(rank10))
+
+
 @pytest.mark.parametrize("gname", ["rmat_small", "web_like", "erdos"])
 def test_bfs_dirop_forced_pull_directed(gname):
     """Direction-optimizing BFS with the switch heuristic skewed so the

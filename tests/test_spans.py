@@ -8,7 +8,8 @@
 * The fetch phase timers fit inside ``io_wait_us``; ``fetch_max_us`` is a
   run's own longest miss, and an injected read delay lands in both.
 * The engine's regime timers and ``sparse_edges_touched`` are filled on
-  the fused and the per-round path alike.
+  the fused and the per-round path alike, and ``stretches`` counts the
+  ``engine.stretch`` spans on both.
 """
 
 import glob
@@ -122,6 +123,23 @@ def test_engine_regime_timers_and_sparse_slots(fused):
     assert (st.dense_us > 0) == (st.dense_rounds > 0)
     if not st.dense_rounds:
         assert st.sparse_edges_touched == st.edges_touched
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "per-round"])
+def test_stretches_count_the_stretch_spans(tmp_path, fused):
+    """``RunStats.stretches`` is one per closed ``engine.stretch`` span:
+    one per stretch fused, one per round (sparse or dense) per round."""
+    g = _bfs_graph()
+    (dist, st), events = _traced(
+        tmp_path, lambda: jax.block_until_ready(
+            bfs.bfs_dd_sparse(g, 0, fused=fused)))
+    stretches = [e for e in events if e[0] == "engine.stretch"]
+    assert st.stretches == len(stretches) > 0
+    if fused:
+        assert st.stretches <= st.rounds
+    else:
+        assert st.stretches == st.rounds
+        assert st.dense_rounds > 0
 
 
 def test_fetch_phase_timers_fit_io_wait():

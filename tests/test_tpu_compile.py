@@ -164,6 +164,22 @@ def test_sparse_ladder_bfs_stretch_fits_one_chip(one_chip):
     assert not _sorts(compiled)
 
 
+def test_resident_pagerank_fits_one_chip(one_chip):
+    """A whole resident PageRank run, the one program ``pr_pull`` compiles
+    for every ``max_iters``: the pull over the CSC mirror in a loop."""
+    from repro.core.algorithms import pagerank
+    i32 = lambda k: _sds((k,), jnp.int32, one_chip)
+    g = dataclasses.replace(
+        _graph(one_chip), in_row_ptr=i32(N_PAD + 1), in_col_idx=i32(M_PAD),
+        in_src_idx=i32(M_PAD), in_edge_w=_sds((M_PAD,), jnp.float32,
+                                              one_chip), in_deg=i32(N_PAD))
+    compiled = pagerank._pr_pull_resident.lower(
+        g, _sds((), jnp.int32, one_chip), damping=0.85, tol=0.0,
+        sub="jnp", det=False).compile()
+    assert 0 < _device_bytes(compiled) < HBM_BYTES
+    assert not _sorts(compiled)
+
+
 def test_tiered_shard_relax_fits_one_chip(one_chip):
     """The tiered path's per-shard relax at the largest shard of a 16-way
     cut (every shard is padded to it)."""
